@@ -3,16 +3,22 @@ batched generate() requests through the TrIMS-backed engine, and compare the
 FaaS cold-start baseline against warm shared serving.
 
     PYTHONPATH=src python examples/serve_llm.py [--arch olmo-1b] [--requests 4]
+
+The model is published at its published widths with seeded weights; pass
+``--reduced`` for the tiny config that runs in seconds on a CPU. Exits
+non-zero if any request fails.
 """
 import argparse
+import sys
 import tempfile
 import time
 
 import jax
 import numpy as np
 
-from repro.configs import get_config, list_archs
+from repro.configs import list_archs
 from repro.core import DiskStore, MRM
+from repro.launch.serve import serving_config
 from repro.models import init_params
 from repro.serving import InferenceEngine, Request, ServingWorkers, publish_model
 
@@ -20,6 +26,8 @@ from repro.serving import InferenceEngine, Request, ServingWorkers, publish_mode
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list_archs())
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny reduced config (CPU rehearsal)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -28,10 +36,9 @@ def main():
 
     root = tempfile.mkdtemp(prefix="trims_serve_")
     disk = DiskStore(f"{root}/models")
-    cfg = get_config(args.arch).reduced()
-    if cfg.n_experts:
-        cfg = cfg.replace(moe_impl="ragged")
-    print(f"publishing {args.arch} (reduced: {cfg.param_count()/1e6:.1f}M params)")
+    cfg = serving_config(args.arch, reduced=args.reduced)
+    print(f"publishing {args.arch} (d_model={cfg.d_model}, "
+          f"{cfg.param_count()/1e6:.1f}M params)")
     publish_model(disk, cfg, init_params(cfg, jax.random.PRNGKey(0)),
                   name=args.arch)
     toks = np.random.default_rng(0).integers(
@@ -61,11 +68,14 @@ def main():
     workers.drain(reqs)
     wall = time.perf_counter() - t0
     workers.stop()
-    ok = sum(1 for r in reqs if not isinstance(r.result, Exception))
-    print(f"concurrent: {ok}/{len(reqs)} requests ok in {wall:.2f}s, "
-          f"disk loads={mrm.stats()['disk_loads']}, "
+    failed = [r.result for r in reqs if isinstance(r.result, Exception)]
+    print(f"concurrent: {len(reqs) - len(failed)}/{len(reqs)} requests ok in "
+          f"{wall:.2f}s, disk loads={mrm.stats()['disk_loads']}, "
           f"exe cache hits={engine.exe_cache_hits}")
+    for exc in failed[:3]:
+        print(f"  failed: {exc!r}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -12,8 +12,9 @@ VMEM scratch — same math, no inter-core reduction needed. GQA query heads of
 one KV head are packed into a single (group x D) MXU operand, so the kernel
 does real matmuls instead of vector dots.
 
-Grid: (B, Hkv, nK). KV-length masking skips whole blocks past ``kv_len``
-(``pl.when``), masking the boundary block with iota.
+Grid: (B, Hkv, nK). ``kv_len`` is a scalar-prefetch operand (SMEM), read
+per batch row; KV-length masking skips whole blocks past it (``pl.when``),
+masking the boundary block with iota.
 """
 from __future__ import annotations
 
@@ -24,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.jax_compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -40,7 +39,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
     k_start = ki * block_k
 
     def _compute():
@@ -87,23 +86,29 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qg = q.reshape(B, Hkv, group, D)
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_k=block_k, n_k=n_k)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, n_k),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
-            pl.BlockSpec((1, 1, group, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, group, D), lambda b, h, ki, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, ki, lens: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, ki, lens: (b, h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, D),
+                               lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, D), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

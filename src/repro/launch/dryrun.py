@@ -25,7 +25,7 @@ from repro.configs import (ARCHS, SHAPES_BY_NAME, cell_applicable, get_config,
 from repro.launch import sharding as shd
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import (batch_axes, make_production_mesh, model_axis,
-                               n_chips, set_mesh)
+                               n_chips)
 from repro.launch.specs import input_specs
 from repro.launch.train_step import (make_decode_step, make_optimizer,
                                      make_prefill_step, make_train_step)
@@ -100,7 +100,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     ba = ba if len(ba) > 1 else (ba[0] if ba else None)
 
     t0 = time.time()
-    with part.activation_axes(ba, model_axis(mesh)), set_mesh(mesh):
+    with part.activation_axes(ba, model_axis(mesh)), jax.set_mesh(mesh):
         lowered = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0

@@ -10,24 +10,12 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-from repro.jax_compat import AxisType
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` (axis_types only where supported)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on new jax,
-    the Mesh's own resource-env context on older versions."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # Mesh is a context manager itself on older jax
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,8 +23,7 @@ from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import Prefetcher, make_batch
 from repro.launch import sharding as shd
-from repro.launch.mesh import (batch_axes, make_local_mesh, model_axis,
-                               set_mesh)
+from repro.launch.mesh import batch_axes, make_local_mesh, model_axis
 from repro.launch.train_step import make_optimizer, make_train_step
 from repro.models import model as M
 from repro.models import partitioning as part
@@ -92,7 +91,7 @@ class Trainer:
                         start_step=start)
         next_step = start
         try:
-            with part.activation_axes(*self._act_axes), set_mesh(self.mesh):
+            with part.activation_axes(*self._act_axes), jax.set_mesh(self.mesh):
                 for _ in range(start, steps):
                     step_idx, batch = next(pf)
                     t0 = time.perf_counter()

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.jax_compat import shard_map
 from repro.models import partitioning as part
 from repro.models.layers import dense_init
 
@@ -277,11 +276,11 @@ def moe_ep_shardmap(cfg: ModelConfig, p: Params, x: jnp.ndarray
         out = jnp.zeros((B, S + 1, D), dt).at[rows, slot_tok].add(ye)[:, :S]
         return jax.lax.psum(out, "model"), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(), P("model"), P("model"), P("model")),
         out_specs=(P(), P()),
-        axis_names={"model"}, check=False,
+        axis_names={"model"}, check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if cfg.n_shared_experts:
         out = out + _shared_ffn(p, x.reshape(B * S, D)).reshape(B, S, D)

@@ -194,14 +194,5 @@ def _size(ax) -> int:
 
 
 def _cur_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax.interpreters import pxla
-        return pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None

@@ -21,8 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.jax_compat import shard_map
-
 
 def quantized_allreduce(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Inside shard_map: sum ``x`` over ``axis_name`` with int8 wire format."""
@@ -53,11 +51,11 @@ def make_compressed_grad_fn(loss_fn, mesh, pod_axis: str = "pod"):
         summed = tree_quantized_allreduce(grads, pod_axis)
         return jax.tree.map(lambda g: g / n, summed)
 
-    return shard_map(
+    return jax.shard_map(
         per_pod_grad, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),            # params: replicated over pod
                   jax.sharding.PartitionSpec(pod_axis)),   # batch dim 0 across pods
         out_specs=jax.sharding.PartitionSpec(),
-        check=False,
+        check_vma=False,
         axis_names={pod_axis},
     )

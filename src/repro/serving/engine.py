@@ -49,6 +49,17 @@ def publish_model(disk: DiskStore, cfg: ModelConfig, params,
     return key
 
 
+def _prefill_batch(cfg: ModelConfig, tokens: np.ndarray) -> Dict[str, Any]:
+    """Prefill inputs for (B, S) prompt ``tokens``; families with a stubbed
+    encoder/vision frontend get zero frontend embeddings."""
+    B, S = tokens.shape
+    batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
+    if cfg.family in ("vlm", "encdec"):
+        batch["frontend"] = jnp.zeros(
+            (B, cfg.n_frontend_tokens or S, cfg.d_model), jnp.float32)
+    return batch
+
+
 @dataclass
 class ServableModel:
     key: ModelKey
@@ -240,6 +251,22 @@ class InferenceEngine:
                 return r
         return self._generate_batch(name, tokens, max_new_tokens, version)
 
+    def prefill_logits(self, name: str, tokens: np.ndarray,
+                       max_new_tokens: int = 8, version: str = "1"
+                       ) -> np.ndarray:
+        """Last-position prefill logits (B, V) as float32, through the same
+        load path and cached prefill program as :meth:`generate`."""
+        sm, _ = self.load_model(name, version)
+        try:
+            B, S = tokens.shape
+            exe, _, sig = self._executable(sm.cfg, "prefill", B, S,
+                                           S + max_new_tokens)
+            (logits, _), _ = self._run_exe(sig, exe, sm.params,
+                                           _prefill_batch(sm.cfg, tokens))
+            return np.asarray(logits, np.float32)
+        finally:
+            self.release(sm)
+
     def _generate_batch(self, name: str, tokens: np.ndarray,
                         max_new_tokens: int, version: str
                         ) -> Tuple[np.ndarray, RequestStats]:
@@ -252,10 +279,7 @@ class InferenceEngine:
         extra_c = 0.0
 
         t0 = time.perf_counter()
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
-        if sm.cfg.family in ("vlm", "encdec"):
-            batch["frontend"] = jnp.zeros(
-                (B, sm.cfg.n_frontend_tokens or S, sm.cfg.d_model), jnp.float32)
+        batch = _prefill_batch(sm.cfg, tokens)
         pkey = None
         hit = None
         if self.prefix_kv is not None:

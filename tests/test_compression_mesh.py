@@ -7,13 +7,7 @@ import textwrap
 
 import pytest
 
-pytestmark = [
-    pytest.mark.slow,  # skipped by scripts/ci.sh --fast
-    pytest.mark.skipif(
-        __import__("repro.jax_compat", fromlist=["AxisType"]).AxisType is None,
-        reason="partial-manual shard_map trips an XLA SPMD partitioner CHECK "
-               "on jax<0.5 (see EXPERIMENTS pin in the module docstring)"),
-]
+pytestmark = pytest.mark.slow  # skipped by scripts/ci.sh --fast
 
 PROBE = textwrap.dedent("""
     import os, json, sys
@@ -21,7 +15,7 @@ PROBE = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.runtime.compression import make_compressed_grad_fn
 
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -36,14 +30,14 @@ PROBE = textwrap.dedent("""
              "y": jax.random.normal(k, (8, 4), jnp.float32)}
 
     grad_fn = make_compressed_grad_fn(loss_fn, mesh, pod_axis="pod")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_comp = jax.jit(grad_fn)(params, batch)
     g_exact = jax.grad(loss_fn)(params, batch)
 
     err = float(jnp.max(jnp.abs(g_comp["w"] - g_exact["w"])))
     scale = float(jnp.max(jnp.abs(g_exact["w"]))) / 127
     # wire dtype check on the lowered module
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(grad_fn).lower(params, batch).as_text()
     has_i8 = ("i8" in txt) or ("s8[" in txt)
     print(json.dumps({"err": err, "scale_bound": scale * 0.51 + 1e-6,

@@ -19,7 +19,8 @@ def test_flash_attention_grad_matches_reference(causal):
 
     def via_kernel(q, k, v):
         return ops.flash_attention(q, k, v, causal=causal,
-                                   block_q=64, block_k=64).sum()
+                                   block_q=64, block_k=64,
+                                   interpret=True).sum()
 
     def via_ref(q, k, v):
         qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
@@ -37,6 +38,7 @@ def test_flash_attention_value_under_jit_grad():
     q = jnp.asarray(rng.standard_normal((1, 64, 2, 16)), jnp.float32)
     loss, grads = jax.jit(jax.value_and_grad(
         lambda q: ops.flash_attention(q, q, q, causal=True,
-                                      block_q=64, block_k=64).sum()))(q)
+                                      block_q=64, block_k=64,
+                                      interpret=True).sum()))(q)
     assert np.isfinite(float(loss))
     assert np.isfinite(np.asarray(grads)).all()

@@ -5,6 +5,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import decode_reference, mha_reference, rmsnorm_reference
@@ -111,3 +112,21 @@ def test_flash_attention_vs_model_attention():
                           block_q=64, block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(jnp.swapaxes(got, 1, 2)),
                                np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+OPS_CALLS = {
+    "flash_attention": lambda x: ops.flash_attention(x, x, x),
+    "decode_attention": lambda x: ops.decode_attention(
+        x[:, 0], x, x, jnp.full((x.shape[0],), 8, jnp.int32)),
+    "rmsnorm": lambda x: ops.rmsnorm(x, jnp.ones((x.shape[-1],))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(OPS_CALLS))
+def test_ops_refuse_non_tpu_backend_without_interpret(name):
+    """Off the chip a kernel runs only when the interpreter is asked for:
+    no silent fallback to the interpreter or to the jnp reference."""
+    assert jax.default_backend() != "tpu"
+    x = jnp.ones((1, 8, 2, 16), jnp.float32)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        OPS_CALLS[name](x)

@@ -11,13 +11,7 @@ import textwrap
 
 import pytest
 
-pytestmark = [
-    pytest.mark.slow,  # skipped by scripts/ci.sh --fast
-    pytest.mark.skipif(
-        __import__("repro.jax_compat", fromlist=["AxisType"]).AxisType is None,
-        reason="partial-manual shard_map trips an XLA SPMD partitioner CHECK "
-               "on jax<0.5 (see EXPERIMENTS pin in the module docstring)"),
-]
+pytestmark = pytest.mark.slow  # skipped by scripts/ci.sh --fast
 
 PROBE = textwrap.dedent("""
     import os, json, sys
@@ -25,7 +19,7 @@ PROBE = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
-    from repro.launch.mesh import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.moe import apply_moe, init_moe
     from repro.models import partitioning as part
 
@@ -35,7 +29,7 @@ PROBE = textwrap.dedent("""
     p = init_moe(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model),
                           jnp.float32)
-    with part.activation_axes("data", "model"), set_mesh(mesh):
+    with part.activation_axes("data", "model"), jax.set_mesh(mesh):
         oe, ae = jax.jit(lambda p, x: apply_moe(
             cfg.replace(moe_impl="ep"), p, x))(p, x)
         g = jax.jit(jax.grad(lambda p, x: apply_moe(

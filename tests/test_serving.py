@@ -100,3 +100,51 @@ def test_arch_signature_stable():
     c3 = c1.replace(n_layers=3)
     assert arch_signature(c1) == arch_signature(c2)
     assert arch_signature(c1) != arch_signature(c3)
+
+
+def test_serve_once_reduced_cold_then_device_matches_no_trims(tmp_path):
+    """The launcher's serving function (the one chip_smoke.py drives at full
+    width): cold disk open, then a device-tier hit, both token-identical to
+    the engine without TrIMS; prefill logits agree with the first token."""
+    from repro.launch.serve import serve_once
+
+    r = serve_once("olmo-1b", reduced=True, store_dir=str(tmp_path / "m"),
+                   requests=2, baseline=True, max_new=4, prompt_len=16)
+    assert r["cfg"] == get_config("olmo-1b").reduced()
+    assert [st.tier_hit for st in r["stats"]] == ["disk", "device"]
+    base_out, base_st = r["baseline"]
+    assert base_st.tier_hit == "none(cold)"
+    for out in r["outputs"]:
+        np.testing.assert_array_equal(out, base_out)
+    assert r["prompt"].max() < r["cfg"].vocab_size
+    logits = r["engine"].prefill_logits("olmo-1b", r["prompt"], 4)
+    assert logits.shape == (1, r["cfg"].padded_vocab)
+    np.testing.assert_array_equal(logits.argmax(-1), base_out[:, 0])
+    assert r["mrm_stats"]["disk_loads"] == 1
+
+
+def test_serve_once_refuses_store_with_other_config(tmp_path):
+    from repro.launch.serve import serve_once
+
+    disk = DiskStore(str(tmp_path / "m"))
+    other = get_config("olmo-1b").reduced().replace(n_layers=2)
+    publish_model(disk, other, init_params(other, jax.random.PRNGKey(0)),
+                  name="olmo-1b")
+    with pytest.raises(ValueError, match="n_layers=2"):
+        serve_once("olmo-1b", reduced=True, store_dir=disk.root)
+
+
+def test_compile_cache_dir_from_env_or_fixed_repo_path(tmp_path, monkeypatch):
+    from repro.launch.serve import REPO_ROOT, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # left alone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = str(REPO_ROOT / ".jax_cache")
+        assert enable_compile_cache() == path
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

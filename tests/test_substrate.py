@@ -119,23 +119,21 @@ class TestCompression:
         mesh = jax.make_mesh((1,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (64, 64), jnp.float32)
 
-        from repro.jax_compat import shard_map
-        out = shard_map(
+        out = jax.shard_map(
             lambda v: quantized_allreduce(v, "pod"), mesh=mesh,
             in_specs=jax.sharding.PartitionSpec(),
             out_specs=jax.sharding.PartitionSpec(),
-            check=False, axis_names={"pod"})(x)
+            check_vma=False, axis_names={"pod"})(x)
         err = np.abs(np.asarray(out) - np.asarray(x)).max()
         scale = float(jnp.abs(x).max()) / 127
         assert err <= scale * 0.51 + 1e-7   # quantization bound
 
     def test_quantized_wire_is_int8(self):
         mesh = jax.make_mesh((1,), ("pod",))
-        from repro.jax_compat import shard_map
-        f = shard_map(lambda v: quantized_allreduce(v, "pod"), mesh=mesh,
-                      in_specs=jax.sharding.PartitionSpec(),
-                      out_specs=jax.sharding.PartitionSpec(),
-                      check=False, axis_names={"pod"})
+        f = jax.shard_map(lambda v: quantized_allreduce(v, "pod"), mesh=mesh,
+                          in_specs=jax.sharding.PartitionSpec(),
+                          out_specs=jax.sharding.PartitionSpec(),
+                          check_vma=False, axis_names={"pod"})
         txt = jax.jit(f).lower(
             jax.ShapeDtypeStruct((128, 128), jnp.float32)).as_text()
         assert "all_gather" in txt or "all-gather" in txt
