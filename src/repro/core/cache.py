@@ -15,6 +15,8 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Dict, Hashable, List, Optional
 
+from repro.runtime import spans
+
 
 class Tier(Enum):
     DEVICE = 0   # TPU HBM (GPU memory in the paper)
@@ -375,7 +377,9 @@ class TierHierarchy:
                 return False
         # D2H copy outside both cache locks: a multi-GB demotion must not
         # block concurrent hits/stagings on either tier
-        payload = self.demote_fn(victim)
+        with spans.span("mrm.demote", bytes=victim.nbytes,
+                        model=getattr(victim.key, "name", str(victim.key))):
+            payload = self.demote_fn(victim)
         if payload is None:
             self.demotion_drops += 1
             return False

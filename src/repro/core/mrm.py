@@ -40,6 +40,7 @@ from repro.core.pipeline import plan_chunks, run_pipeline
 from repro.core.slo import DEFAULT_HORIZON_S, SLOState
 from repro.core.store import CloudStore, DiskStore, ModelFile, _np_dtype
 from repro.core.tenant import RequestContext
+from repro.runtime import spans
 
 # write-back queue shutdown sentinel (MRM.shutdown)
 _WB_SENTINEL = object()
@@ -69,7 +70,8 @@ class OpenTimings:
     decompress_s: float = 0.0     # measured inflate busy s (cloud/peer fetch)
     disk_read_s: float = 0.0      # measured file -> host bytes
     deserialize_s: float = 0.0    # measured unmarshal -> arrays
-    h2d_measured_s: float = 0.0   # measured jnp staging on this host
+    h2d_measured_s: float = 0.0   # measured enqueue of the H2D copies; the
+                                  # copy itself is the mrm.stage span
     h2d_modeled_s: float = 0.0    # modeled TPU PCIe staging
     share_overhead_s: float = 0.0 # measured handle-creation overhead (o+s per object)
     total_s: float = 0.0
@@ -454,6 +456,10 @@ class MRM:
         self._wb_lock = threading.Lock()
         if writeback_to_cloud and objectstore is not None:
             self._start_writeback()
+        # ends traced mrm.stage spans once the copies land (_stage_done);
+        # started by the first staging made while a profiler records
+        self._stage_queue = None
+        self._stage_waiter = None
 
     def attach_objectstore(self, objectstore) -> None:
         """Late-bind the CLOUD tier (the ``Cluster.add_node`` path); arms
@@ -1110,6 +1116,51 @@ class MRM:
                 self._wb_queue.put(_WB_SENTINEL)
         if thread is not None:
             thread.join(timeout)
+        with self._lock:
+            waiter, self._stage_waiter = self._stage_waiter, None
+        if waiter is not None:
+            self._stage_queue.put(None)
+            waiter.join(timeout)
+
+    def _stage_span(self, key, nbytes: int, source: str,
+                    fut: Optional[LoadFuture]):
+        """Enter the ``mrm.stage`` span of one staging; None unless a
+        profiler records."""
+        if not spans.tracing():
+            return None
+        sp = spans.span("mrm.stage", model=key.name, bytes=nbytes,
+                        source=source,
+                        prefetch=int(fut is not None and not fut.want_handle))
+        sp.__enter__()
+        return sp
+
+    def _stage_done(self, sp, weights: Dict[str, object]) -> None:
+        """Hand ``sp`` and the staged arrays to the waiter thread, which
+        ends the span once every copy is on the device; the open goes on
+        without waiting."""
+        if sp is None:
+            return
+        with self._lock:
+            if self._stage_waiter is None:
+                import queue
+                self._stage_queue = queue.SimpleQueue()
+                self._stage_waiter = threading.Thread(
+                    target=self._wait_stages, args=(self._stage_queue,),
+                    daemon=True, name="mrm-stage-waiter")
+                self._stage_waiter.start()
+            self._stage_queue.put((sp, list(weights.values())))
+
+    @staticmethod
+    def _wait_stages(q) -> None:
+        import jax
+        while (item := q.get()) is not None:
+            sp, arrays = item
+            try:
+                jax.block_until_ready(arrays)
+            except RuntimeError:  # a copy freed or failed ends its span too
+                pass
+            finally:
+                sp.__exit__(None, None, None)
 
     def _shm_views(self, key, specs):
         """One segment with tensors packed back-to-back. ``specs`` is
@@ -1208,6 +1259,7 @@ class MRM:
             d_entry.pinned = True
         h_entry = None
         adopted = None
+        sp = None
         segs = []
         try:
             # reserve HOST room for the incoming model BEFORE demoting the
@@ -1228,6 +1280,9 @@ class MRM:
                     self.tiers.make_room(Tier.HOST, nbytes)
                     h_entry = self.host.insert(key, nbytes, payload=None)
                     h_entry.pinned = True
+            if adopted is None:
+                # from the demotion of the device victims to the last copy
+                sp = self._stage_span(key, nbytes, "disk", fut)
             demoted = self.tiers.demote_evicted(evicted)
             timings.demote_s = sum(self.hw.d2h_time(v.nbytes) for v in demoted)
             if demoted:
@@ -1268,6 +1323,8 @@ class MRM:
                      ("h2d", put_chunk)],
                     depth=self.pipeline_depth)
         except BaseException:
+            if sp is not None:
+                sp.__exit__(None, None, None)
             # roll back both reservations or the pinned placeholders brick
             # the key (payload-None entries are treated as misses, but the
             # next loader's insert would collide)
@@ -1281,6 +1338,7 @@ class MRM:
             for seg in segs:
                 seg.close_and_unlink()
             raise
+        self._stage_done(sp, weights)
 
         timings.disk_read_s = report.stage("disk_read").busy_s
         timings.deserialize_s = report.stage("deserialize").busy_s
@@ -1552,18 +1610,20 @@ class MRM:
         """HOST hit -> device: chunked H2D (double-buffered when pipelined)."""
         nbytes = host_entry.nbytes
         need = nbytes + activation_bytes
-        # reserve capacity atomically (make_room + insert under one lock):
-        # concurrent stages of DIFFERENT models must not steal each other's
-        # freed room between eviction and insertion; victims demote to HOST
-        # after the lock drops (D2H copy must not stall other opens)
-        with self.device.lock:
-            evicted = self.tiers.make_room(Tier.DEVICE, need)
-            entry = self.device.insert(key, nbytes, payload=None)
-            entry.pinned = True
-
+        sp = self._stage_span(key, nbytes, "host", fut)
         hm: HostModel = host_entry.payload
         weights: Dict[str, object] = {}
+        entry = None
         try:
+            # reserve capacity atomically (make_room + insert under one
+            # lock): concurrent stages of DIFFERENT models must not steal
+            # each other's freed room between eviction and insertion;
+            # victims demote to HOST after the lock drops (D2H copy must
+            # not stall other opens)
+            with self.device.lock:
+                evicted = self.tiers.make_room(Tier.DEVICE, need)
+                entry = self.device.insert(key, nbytes, payload=None)
+                entry.pinned = True
             demoted = self.tiers.demote_evicted(evicted)
             timings.demote_s = sum(self.hw.d2h_time(v.nbytes) for v in demoted)
             if demoted:
@@ -1595,10 +1655,14 @@ class MRM:
                     weights[n] = self.device_put_fn(a)
                 timings.h2d_measured_s = time.perf_counter() - t0
         except BaseException:
-            with self.device.lock:
-                if self.device.peek(key) is entry:
-                    self.device.remove(key)
+            if sp is not None:
+                sp.__exit__(None, None, None)
+            if entry is not None:
+                with self.device.lock:
+                    if self.device.peek(key) is entry:
+                        self.device.remove(key)
             raise
+        self._stage_done(sp, weights)
         self._record_staging_models(timings, nbytes)
         self._maybe_simulate_h2d(timings)
         with self._lock:

@@ -17,7 +17,7 @@ import hashlib
 import json
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -29,6 +29,7 @@ from repro.core.client import LoadedModel, TrimsClient, cold_load, free_model
 from repro.core.mrm import MRM, ModelKey
 from repro.core.store import DiskStore
 from repro.models import model as M
+from repro.runtime import spans
 from repro.serving.weights_io import (flat_to_params, flat_to_params_like,
                                       params_to_flat)
 
@@ -104,7 +105,6 @@ class InferenceEngine:
         self._exe_compiled: set = set()   # sigs whose first call was timed
         self._cfg_cache: Dict[Tuple[str, str], ModelConfig] = {}
         self._lock = threading.RLock()
-        self.stats: List[RequestStats] = []
         self.exe_cache_hits = 0
         self.exe_cache_misses = 0
         self.prefix_kv = None
@@ -125,19 +125,24 @@ class InferenceEngine:
         key = ModelKey(FRAMEWORK, name, version)
         cfg = self._cfg_cache.get((name, version)) or self._config_for(key)
         self._cfg_cache[(name, version)] = cfg
-        t0 = time.perf_counter()
-        if self.use_trims:
-            h = self.trims.open(FRAMEWORK, name, version)
-            loaded = LoadedModel(key, h.weights, h.nbytes, h.timings,
-                                 via_trims=True, handle=h)
-        else:
-            loaded = cold_load(self.disk, key)
-        load_s = time.perf_counter() - t0
-        template = jax.eval_shape(
-            lambda k: M.init_params(cfg, k), jax.random.PRNGKey(0))
-        params = flat_to_params_like(
-            template, loaded.weights,
-            convert=lambda v: v if hasattr(v, "devices") else jnp.asarray(v))
+        with spans.request() as req:
+            t0 = time.perf_counter()
+            with spans.span("engine.open", req=req, model=name) as sp:
+                if self.use_trims:
+                    h = self.trims.open(FRAMEWORK, name, version)
+                    loaded = LoadedModel(key, h.weights, h.nbytes, h.timings,
+                                         via_trims=True, handle=h)
+                else:
+                    loaded = cold_load(self.disk, key)
+                sp.set_metadata(tier=loaded.timings.tier_hit)
+            load_s = time.perf_counter() - t0
+            with spans.span("engine.params", req=req):
+                template = jax.eval_shape(
+                    lambda k: M.init_params(cfg, k), jax.random.PRNGKey(0))
+                params = flat_to_params_like(
+                    template, loaded.weights,
+                    convert=lambda v: v if hasattr(v, "devices")
+                    else jnp.asarray(v))
         return ServableModel(key, cfg, params, loaded, loaded.nbytes), load_s
 
     def release(self, sm: ServableModel):
@@ -244,12 +249,17 @@ class InferenceEngine:
 
         With ``streaming`` on, cold DENSE/MOE loads are served layer by
         layer against a partial open (same tokens, earlier first token);
-        anything else falls through to the batch path below."""
-        if self.streaming:
-            r = self._generate_streaming(name, tokens, max_new_tokens, version)
-            if r is not None:
-                return r
-        return self._generate_batch(name, tokens, max_new_tokens, version)
+        anything else falls through to the batch path below.
+
+        Its spans carry the request id the caller bound (``spans.request``),
+        else the next one."""
+        with spans.request():
+            if self.streaming:
+                r = self._generate_streaming(name, tokens, max_new_tokens,
+                                             version)
+                if r is not None:
+                    return r
+            return self._generate_batch(name, tokens, max_new_tokens, version)
 
     def prefill_logits(self, name: str, tokens: np.ndarray,
                        max_new_tokens: int = 8, version: str = "1"
@@ -277,34 +287,38 @@ class InferenceEngine:
         exe_p, c1, sig_p = self._executable(sm.cfg, "prefill", B, S, max_len)
         exe_d, c2, sig_d = self._executable(sm.cfg, "decode", B, 1, max_len)
         extra_c = 0.0
+        req = spans.request_id()
 
         t0 = time.perf_counter()
-        batch = _prefill_batch(sm.cfg, tokens)
-        pkey = None
-        hit = None
-        if self.prefix_kv is not None:
-            from repro.serving.prefix_cache import prompt_key
-            pkey = prompt_key(name, tokens, max_len)
-            hit = self.prefix_kv.lookup(pkey)
-        if hit is not None:
-            logits, cache = hit  # immutable jax arrays: zero-copy share
-        else:
-            (logits, cache), dc = self._run_exe(sig_p, exe_p, sm.params, batch)
-            extra_c += dc
+        with spans.span("engine.prefill", req=req, S=S):
+            batch = _prefill_batch(sm.cfg, tokens)
+            pkey = None
+            hit = None
             if self.prefix_kv is not None:
-                self.prefix_kv.insert(pkey, logits, cache,
-                                      time.perf_counter() - t0)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(tok)
-        ttft_s = time.perf_counter() - t_start
-        out = [tok]
-        for i in range(max_new_tokens - 1):
-            (logits, cache), dc = self._run_exe(
-                sig_d, exe_d, sm.params, cache, tok, jnp.int32(S + i))
-            extra_c += dc
+                from repro.serving.prefix_cache import prompt_key
+                pkey = prompt_key(name, tokens, max_len)
+                hit = self.prefix_kv.lookup(pkey)
+            if hit is not None:
+                logits, cache = hit  # immutable jax arrays: zero-copy share
+            else:
+                (logits, cache), dc = self._run_exe(sig_p, exe_p, sm.params,
+                                                    batch)
+                extra_c += dc
+                if self.prefix_kv is not None:
+                    self.prefix_kv.insert(pkey, logits, cache,
+                                          time.perf_counter() - t0)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(tok)
-        result = np.asarray(jnp.stack(out, axis=1))
+            jax.block_until_ready(tok)
+            ttft_s = time.perf_counter() - t_start
+        out = [tok]
+        with spans.span("engine.decode", req=req, steps=max_new_tokens - 1):
+            for i in range(max_new_tokens - 1):
+                (logits, cache), dc = self._run_exe(
+                    sig_d, exe_d, sm.params, cache, tok, jnp.int32(S + i))
+                extra_c += dc
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out.append(tok)
+            result = np.asarray(jnp.stack(out, axis=1))
         compute_s = max(0.0, time.perf_counter() - t0 - extra_c)
 
         tm = sm.loaded.timings
@@ -314,7 +328,6 @@ class InferenceEngine:
             compile_s=c1 + c2 + extra_c, compute_s=compute_s,
             total_s=time.perf_counter() - t_start,
             modeled_load_s=tm.modeled_total(), ttft_s=ttft_s)
-        self.stats.append(st)
         self.release(sm)
         return result, st
 
@@ -451,7 +464,6 @@ class InferenceEngine:
             compile_s=trace_s + extra_c, compute_s=compute_s,
             total_s=time.perf_counter() - t_start,
             modeled_load_s=tm.modeled_total(), ttft_s=ttft_s, streamed=True)
-        self.stats.append(st)
         if h is not None:
             self.mrm.close(h)
         return result, st
@@ -466,10 +478,11 @@ class Request:
     model: str
     tokens: np.ndarray
     max_new: int = 4
-    submitted: float = field(default_factory=time.perf_counter)
     done: Optional[threading.Event] = None
     result: Any = None
     stats: Optional[RequestStats] = None
+    id: int = -1                  # numbered by ServingWorkers.submit
+    queued: Any = None            # its serving.queue span, until taken
 
 
 class ServingWorkers:
@@ -491,6 +504,10 @@ class ServingWorkers:
 
     def submit(self, req: Request) -> Request:
         req.done = threading.Event()
+        req.id = spans.next_request_id()
+        # entered here, exited by the worker that takes the request
+        req.queued = spans.span("serving.queue", req=req.id)
+        req.queued.__enter__()
         self.q.put(req)
         return req
 
@@ -515,29 +532,41 @@ class ServingWorkers:
         nxt = self._peek_next_models(1)
         return nxt[0] if nxt else None
 
+    def _prefetch_next(self, req: Request) -> int:
+        """Hint the MRM toward the models queued behind ``req``; returns
+        the number of hints issued."""
+        eng = self.engine
+        hints = 0
+        for nxt in self._peek_next_models(self.lookahead):
+            if nxt == req.model:
+                continue
+            if eng.use_trims:
+                from repro.core.cache import Tier
+                k = ModelKey(FRAMEWORK, nxt, "1")
+                if eng.mrm.resident(k, Tier.DEVICE):
+                    continue   # already staged: the hint is free work
+            # overlap the NEXT requests' model staging with THIS
+            # request's load+compute (async MRM load, zero refs);
+            # with streaming on, non-disk-resident targets warm
+            # through a partial open (layer hints ride along)
+            eng.prefetch(nxt)
+            hints += 1
+        return hints
+
     def _run(self):
         while True:
             req = self.q.get()
             if req is None:
                 return
+            req.queued.__exit__(None, None, None)
+            req.queued = None
             if self.lookahead_prefetch:
-                eng = self.engine
-                for nxt in self._peek_next_models(self.lookahead):
-                    if nxt == req.model:
-                        continue
-                    if eng.use_trims:
-                        from repro.core.cache import Tier
-                        k = ModelKey(FRAMEWORK, nxt, "1")
-                        if eng.mrm.resident(k, Tier.DEVICE):
-                            continue   # already staged: the hint is free work
-                    # overlap the NEXT requests' model staging with THIS
-                    # request's load+compute (async MRM load, zero refs);
-                    # with streaming on, non-disk-resident targets warm
-                    # through a partial open (layer hints ride along)
-                    eng.prefetch(nxt)
+                with spans.span("serving.lookahead", req=req.id) as sp:
+                    sp.set_metadata(hints=self._prefetch_next(req))
             try:
-                req.result, req.stats = self.engine.generate(
-                    req.model, req.tokens, req.max_new)
+                with spans.request(req.id):
+                    req.result, req.stats = self.engine.generate(
+                        req.model, req.tokens, req.max_new)
             except Exception as e:  # noqa: BLE001
                 req.result = e
             finally:
