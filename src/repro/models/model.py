@@ -8,6 +8,8 @@ Entry points (all pure functions over (cfg, params, ...)):
   init_cache(cfg, batch, max_len)        -> decode cache pytree (zeros)
   prefill(cfg, params, batch, max_len)   -> (logits, cache)
   decode_step(cfg, params, cache, token, pos) -> (logits, cache)
+  decode_step_rows(cfg, params, caches, tokens, positions)
+                                         -> (logits, caches)  [dense rows]
 
 ``batch`` is a dict: {"tokens": (B,S) int32, "labels": (B,S) int32,
 optional "frontend": (B, S_src, D) precomputed modality embeddings (vlm/audio)}.
@@ -732,6 +734,52 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
 
     logits = _logits(cfg, params, x)
     return logits[:, 0], new_cache
+
+
+def decode_step_rows(cfg: ModelConfig, params: Params, caches, tokens,
+                     positions) -> Tuple[jnp.ndarray, Tuple[Params, ...]]:
+    """One decode step over k rows of one model, each with its own cache.
+
+    caches: k B=1 dense caches as ``prefill`` made them, each at its own
+    ``max_len``; tokens: (k,) int32; positions: (k,) int32, each row's
+    cache length so far. The rows' hidden states are stacked, so the norms
+    and the QKV, output and MLP matrices are read once for all of them;
+    RoPE, the KV write and attention run per row, against that row's cache
+    alone. Row r's result depends only on row r's inputs.
+
+    Returns (logits (k, V), the k new caches). Dense only: an MoE layer's
+    expert capacity can couple the rows of one batch.
+    """
+    if cfg.family != DENSE or cfg.n_experts:
+        raise ValueError(f"decode_step_rows serves dense layers, not "
+                         f"{cfg.family!r} with {cfg.n_experts} experts")
+    k = len(caches)
+    x = part.shard_btd(params["embed"][tokens][:, None, :].astype(cfg.cdtype))
+
+    def body(x, xs):
+        p, cls = xs
+        h = L.apply_norm(cfg, p["ln1"], x)
+        q, kk, v = L.qkv_project(cfg, p["attn"], h, None)
+        new, outs = [], []
+        for r in range(k):
+            # RoPE row by row, as decode_step applies it: rotating the
+            # stacked rows lets XLA fuse the rotation into the projection
+            # with a transposed copy of wq and wk on every step (v5e)
+            at = jnp.full((1, 1), positions[r], jnp.int32)
+            qr = L.apply_rope(q[r:r + 1], at, cfg.rope_theta)
+            kr = L.apply_rope(kk[r:r + 1], at, cfg.rope_theta)
+            cl = _write_kv(cls[r], kr, v[r:r + 1], positions[r])
+            outs.append(L.decode_attention_core(
+                cfg, qr, cl["k"], cl["v"], positions[r:r + 1] + 1))
+            new.append(cl)
+        x = x + L.attention_out(cfg, p["attn"], jnp.concatenate(outs))
+        h = L.apply_norm(cfg, p["ln2"], x)
+        return x + L.apply_mlp(p["ffn"], h), tuple(new)
+
+    x, new = jax.lax.scan(body, x, (params["layers"],
+                                    tuple(c["attn"] for c in caches)))
+    logits = _logits(cfg, params, x)
+    return logits[:, 0], tuple({"attn": c} for c in new)
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ import hashlib
 import json
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import DENSE, ModelConfig
 from repro.core.client import LoadedModel, TrimsClient, cold_load, free_model
 from repro.core.mrm import MRM, ModelKey
 from repro.core.store import DiskStore
@@ -59,6 +60,40 @@ def _prefill_batch(cfg: ModelConfig, tokens: np.ndarray) -> Dict[str, Any]:
         batch["frontend"] = jnp.zeros(
             (B, cfg.n_frontend_tokens or S, cfg.d_model), jnp.float32)
     return batch
+
+
+def _rows_step(cfg: ModelConfig, params, caches, toks, positions):
+    """One decode step over B=1 rows of one model: each row's next token
+    (1,) and new cache."""
+    logits, new = M.decode_step_rows(cfg, params, caches,
+                                     jnp.concatenate(toks), jnp.stack(positions))
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return tuple(nxt[r:r + 1] for r in range(len(toks))), new
+
+
+def _abstract(tree):
+    """Shapes of ``tree``'s arrays, placed as they are: a program lowered
+    for them is the one ``jit`` dispatches to for the arrays themselves."""
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=a.sharding if a.committed else None), tree)
+
+
+@dataclass(eq=False)
+class _Row:
+    """A B=1 request of a dense model in decode: its cache, the next
+    position, the steps it has left and every token made so far (the last
+    is the next step's input). ``busy`` while a thread launches its step."""
+    key: ModelKey
+    arch: str
+    max_len: int
+    params: Any
+    solo: Tuple[Any, Any]         # (sig, exe) of its B=1 decode program
+    cache: Any
+    pos: int
+    left: int
+    out: List[Any]
+    busy: bool = False
+    error: Optional[BaseException] = None
 
 
 @dataclass
@@ -105,6 +140,12 @@ class InferenceEngine:
         self._exe_compiled: set = set()   # sigs whose first call was timed
         self._cfg_cache: Dict[Tuple[str, str], ModelConfig] = {}
         self._lock = threading.RLock()
+        # same-model decode rows (dense, B=1): a step may take two rows whose
+        # two-row program, keyed (arch signature, max_len, max_len), is built
+        self._rows_cv = threading.Condition()
+        self._decoding: Dict[ModelKey, List[_Row]] = {}
+        self._row_specs: Dict[Tuple[str, int], Any] = {}
+        self._row_exe: Dict[Tuple[str, int, int], Any] = {}
         self.exe_cache_hits = 0
         self.exe_cache_misses = 0
         self.prefix_kv = None
@@ -311,13 +352,26 @@ class InferenceEngine:
             jax.block_until_ready(tok)
             ttft_s = time.perf_counter() - t_start
         out = [tok]
+        row = None
+        if (B == 1 and max_new_tokens > 1 and sm.cfg.family == DENSE
+                and not sm.cfg.n_experts):
+            extra_c += self._build_row_programs(sm.cfg, sm.params, cache, tok,
+                                                max_len)
+            row = _Row(sm.key, arch_signature(sm.cfg), max_len, sm.params,
+                       (sig_d, exe_d), cache, S, max_new_tokens - 1, out)
+            cache = None    # the row's steps free each cache they replace
         with spans.span("engine.decode", req=req, steps=max_new_tokens - 1):
-            for i in range(max_new_tokens - 1):
-                (logits, cache), dc = self._run_exe(
-                    sig_d, exe_d, sm.params, cache, tok, jnp.int32(S + i))
-                extra_c += dc
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                out.append(tok)
+            if row is not None:
+                extra_c += self._decode_row(row, req)
+            else:
+                for i in range(max_new_tokens - 1):
+                    with spans.span("engine.step", req=req, rows=1):
+                        (logits, cache), dc = self._run_exe(
+                            sig_d, exe_d, sm.params, cache, tok,
+                            jnp.int32(S + i))
+                    extra_c += dc
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    out.append(tok)
             result = np.asarray(jnp.stack(out, axis=1))
         compute_s = max(0.0, time.perf_counter() - t0 - extra_c)
 
@@ -330,6 +384,127 @@ class InferenceEngine:
             modeled_load_s=tm.modeled_total(), ttft_s=ttft_s)
         self.release(sm)
         return result, st
+
+    # ------------------------------------------------------ decode rows
+    def _build_row_programs(self, cfg: ModelConfig, params, cache, tok,
+                            max_len: int) -> float:
+        """The first time a B=1 dense request decodes at ``max_len`` for its
+        architecture, build the two-row decode programs that pair it with
+        each length already seen there, itself included, so that no row
+        compiles when it joins another. Returns the seconds it took."""
+        arch = arch_signature(cfg)
+        with self._lock:
+            if (arch, max_len) in self._row_specs:
+                return 0.0
+            self._row_specs[(arch, max_len)] = _abstract((cache, tok))
+            lens = sorted(n for a, n in self._row_specs if a == arch)
+        t0 = time.perf_counter()
+        p_spec = _abstract(params)
+        pos = jax.ShapeDtypeStruct((), jnp.int32)
+        exes, lowered = {}, []
+        for n in lens:
+            a, b = sorted((max_len, n))
+            (ca, ta), (cb, tb) = (self._row_specs[(arch, a)],
+                                  self._row_specs[(arch, b)])
+            exes[(arch, a, b)] = exe = jax.jit(
+                lambda p, c, t, i: _rows_step(cfg, p, c, t, i))
+            lowered.append(exe.lower(p_spec, (ca, cb), (ta, tb), (pos, pos)))
+        # compiled in parallel; each fills its jit's cache for these shapes
+        with ThreadPoolExecutor(len(lowered)) as pool:
+            list(pool.map(lambda lo: lo.compile(), lowered))
+        with self._lock:
+            self._row_exe.update(exes)
+        return time.perf_counter() - t0
+
+    def _decode_row(self, row: _Row, req) -> float:
+        """Decode ``row`` to its last token beside the other rows of its
+        model. At each step boundary this thread launches its row's next
+        step, together with one more row of the same model when their
+        two-row program is built and no other thread is launching that row;
+        else alone, with the B=1 program. A row's step waits for the token
+        of its step before last, so at most two are in flight and a row
+        that finishes prefill joins within a step. Returns the seconds the
+        row's first B=1 steps spent compiling."""
+        cv = self._rows_cv
+        compile_s = 0.0
+        with cv:
+            self._decoding.setdefault(row.key, []).append(row)
+        try:
+            while True:
+                with cv:
+                    while row.busy:
+                        cv.wait()
+                    if row.error is not None:
+                        raise row.error
+                    if row.left == 0:
+                        return compile_s
+                    seen = len(row.out)
+                if seen >= 2:   # pace, free for another thread to take
+                    jax.block_until_ready(row.out[seen - 2])
+                with cv:
+                    if row.busy or len(row.out) != seen:
+                        continue            # stepped by another thread
+                    rows = [row] + self._partner(row)
+                    for r in rows:
+                        r.busy = True
+                try:
+                    compile_s += self._step_rows(rows, row.params, req)
+                except Exception as e:
+                    for r in rows:
+                        r.error = e
+                    raise
+                finally:
+                    with cv:
+                        for r in rows:
+                            r.busy = False
+                        cv.notify_all()
+        finally:
+            with cv:
+                group = self._decoding[row.key]
+                group.remove(row)
+                if not group:
+                    del self._decoding[row.key]
+
+    def _partner(self, row: _Row) -> List[_Row]:
+        """Another row of ``row``'s model free to step with it: none busy,
+        steps left, and a built program for the pair. Under ``_rows_cv``."""
+        for other in self._decoding[row.key]:
+            if (other is row or other.busy or other.left == 0
+                    or other.error is not None):
+                continue
+            if (row.arch, *sorted((row.max_len, other.max_len))) \
+                    in self._row_exe:
+                return [other]
+        return []
+
+    def _step_rows(self, rows: List[_Row], params, req) -> float:
+        """Launch one step of ``rows`` (held busy by this thread): the B=1
+        program for one row, the two-row program for two. Returns the
+        seconds a first B=1 call spent compiling."""
+        for r in rows:
+            if len(r.out) >= 2:
+                jax.block_until_ready(r.out[-2])
+        dc = 0.0
+        with spans.span("engine.step", req=req, rows=len(rows)):
+            if len(rows) == 1:
+                (r,) = rows
+                (logits, r.cache), dc = self._run_exe(
+                    *r.solo, r.params, r.cache, r.out[-1], jnp.int32(r.pos))
+                toks = (jnp.argmax(logits, axis=-1).astype(jnp.int32),)
+            else:
+                rows = sorted(rows, key=lambda r: r.max_len)
+                exe = self._row_exe[(rows[0].arch,
+                                     *(r.max_len for r in rows))]
+                toks, caches = exe(params, tuple(r.cache for r in rows),
+                                   tuple(r.out[-1] for r in rows),
+                                   tuple(np.int32(r.pos) for r in rows))
+                for r, c in zip(rows, caches):
+                    r.cache = c
+        for r, t in zip(rows, toks):
+            r.out.append(t)
+            r.pos += 1
+            r.left -= 1
+        return dc
 
     def _generate_streaming(self, name: str, tokens: np.ndarray,
                             max_new_tokens: int, version: str

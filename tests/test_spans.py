@@ -20,6 +20,7 @@ from repro.serving import (FRAMEWORK, InferenceEngine, Request,
                            ServingWorkers, publish_model)
 
 ENGINE = ("engine.open", "engine.params", "engine.prefill", "engine.decode")
+STEP = "engine.step"                        # each decode launch, in decode
 MODELS = ("tiny-a", "tiny-b", "tiny-a")     # three host-tier opens
 
 
@@ -74,7 +75,7 @@ def _serve(disk, nbytes, tokens, wrap=None):
 
 def _host_spans(path):
     """(name, line, start, end, stats) of the spans under test."""
-    names = {"serving.queue", "mrm.stage", "test.put", *ENGINE}
+    names = {"serving.queue", "mrm.stage", "test.put", STEP, *ENGINE}
     (f,) = glob.glob(str(path / "**" / "*.xplane.pb"), recursive=True)
     out = []
     for plane in ProfileData.from_file(f).planes:
@@ -120,13 +121,20 @@ def test_spans_off_start_no_waiter_and_tokens_match_traced(store, tmp_path):
     assert len(stages) == len(MODELS)
     for r, stage in zip(traced, stages):
         got = by_req[r.id]
-        assert sorted(got) == sorted(("serving.queue",) + ENGINE)
+        assert sorted(got) == sorted(("serving.queue", STEP) + ENGINE)
+        steps = got.pop(STEP)
         assert all(len(v) == 1 for v in got.values())
         seq = [got[n][0] for n in ENGINE]
         # the engine's spans run on one thread, one after another
         assert len({line for line, _, _, _ in seq}) == 1
         for (_, _, end, _), (_, start, _, _) in zip(seq, seq[1:]):
             assert end <= start
+        # one step per decode launch, alone (one worker), inside the decode
+        decode = got["engine.decode"][0]
+        assert len(steps) == 2
+        for line, start, end, st in steps:
+            assert line == decode[0] and decode[1] <= start <= end <= decode[2]
+            assert int(st["rows"]) == 1
         opened = seq[0]
         assert opened[3]["model"] == r.model and opened[3]["tier"] == "host"
         assert got["serving.queue"][0][2] <= opened[1]
