@@ -18,12 +18,12 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
 
-from bench import mix, reference, weights as W
+from bench import arch as A, mix, weights as W
 
 GRACE_S = 60.0          # an answer due in the window may come this much later
 MODEL_VERSION = "1"
@@ -33,25 +33,18 @@ def variant_name(config: dict, v: int) -> str:
     return f"{config['program']['arch']}.v{v}"
 
 
-def program_config(config: dict):
+def program_config(config: dict, arch):
     """The program's own config for this configuration, built as
-    ``launch/serve.py`` builds it; refused if it does not state the
-    configuration file's sizes."""
+    ``launch/serve.py`` builds it; refused if it does not show the fields
+    the architecture module asks of the configuration file."""
     from repro.launch.serve import serving_config
 
     prog = config["program"]
+    if config["model"]["dtype"] != "bfloat16":
+        raise ValueError(f"weights are made in bfloat16, not "
+                         f"{config['model']['dtype']}")
     pc = serving_config(prog["arch"], reduced=bool(prog.get("reduced")))
-    m = config["model"]
-    norm = {"rmsnorm": "rmsnorm",
-            "layernorm_nonparametric": "nonparametric_ln"}[m["norm"]]
-    want = {"n_layers": m["n_layers"], "d_model": m["d_model"],
-            "n_heads": m["n_heads"], "n_kv_heads": m["n_kv_heads"],
-            "head_dim": m["head_dim"], "d_ff": m["d_ff"],
-            "vocab_size": m["vocab_size"], "norm_type": norm,
-            "norm_eps": m["norm_eps"], "rope_theta": m["rope_theta"],
-            "tie_embeddings": m["tie_embeddings"], "param_dtype": m["dtype"],
-            "compute_dtype": m["dtype"], "padded_vocab": prog["embed_rows"],
-            "family": "dense", "use_pallas": False}
+    want = arch.program_fields(config)
     got = {k: getattr(pc, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
@@ -95,6 +88,7 @@ class Cell:
     def __init__(self, config: dict, traffic: dict, seed: int,
                  log: Callable[[str], None] = print):
         self.config, self.traffic, self.seed, self.log = config, traffic, seed, log
+        self.arch = A.load(config)
         self.model = config["model"]
         self.embed_rows = config["program"]["embed_rows"]
         self.n_variants = int(traffic["variants"])
@@ -113,7 +107,8 @@ class Cell:
         from repro.serving import InferenceEngine, ServingWorkers, publish_model
 
         t0 = time.perf_counter()
-        pc = program_config(self.config)
+        pc = program_config(self.config, self.arch)
+        layout = self.arch.weight_groups(self.model, self.embed_rows)
         serving = self.traffic["serving"]
         self.store_dir = tempfile.mkdtemp(prefix="bench-store-")
         disk = DiskStore(self.store_dir)
@@ -136,9 +131,9 @@ class Cell:
         with ThreadPoolExecutor(max_workers=1) as pool:
             pending = None
             for v in range(self.n_variants):
-                flat = W.make_flat(self.seed + v, self.model, self.embed_rows)
+                flat = W.make_flat(self.seed + v, layout)
                 self.expected[v] = W.fingerprints(flat)
-                params = W.nest(jax.device_get(flat), self.model)
+                params = self.arch.nest(jax.device_get(flat), self.model)
                 del flat
                 if pending is not None:
                     pending.result()
@@ -368,10 +363,9 @@ class Cell:
             out["control_gap"], out["control_flips"] = 0.0, 0
         for v in sorted({s.variant for s in picked}):
             group = [s for s in picked if s.variant == v]
-            r = reference.served_gaps(self.seed + v, self.model,
-                                      self.embed_rows,
-                                      [s.prompt for s in group],
-                                      [s.tokens for s in group], control)
+            r = served_gaps(self.arch, self.seed + v, self.model,
+                            self.embed_rows, [s.prompt for s in group],
+                            [s.tokens for s in group], control)
             out["gap"] = max([out["gap"]] + [float(g.max()) for g in r["gap"]])
             out["tokens"] += sum(len(s.tokens) for s in group)
             out["flips"] += sum(int((g > 0).sum()) for g in r["gap"])
@@ -381,6 +375,29 @@ class Cell:
                 out["control_flips"] += sum(int((g > 0).sum())
                                             for g in r["control_gap"])
         return out
+
+
+def served_gaps(arch, seed: int, model: dict, embed_rows: int,
+                prompts: Sequence[np.ndarray], served: Sequence[np.ndarray],
+                control: bool = False) -> Dict[str, List[np.ndarray]]:
+    """The architecture's reference over each prompt followed by its served
+    tokens.
+
+    Returns, per request, ``gap``: how far each served token's float32 logit
+    lies below the reference's best at its position; with ``control``, also
+    ``control_gap``: the same for the token the float8 control puts first."""
+    seqs = [np.concatenate([p, s[:-1]]).astype(np.int32)
+            for p, s in zip(prompts, served)]
+    rows = [np.arange(len(p) - 1, len(p) - 1 + len(s)) for p, s in
+            zip(prompts, served)]
+    ref = arch.logits_at(seed, model, embed_rows, seqs, rows)
+    out = {"gap": [r.max(-1) - r[np.arange(len(s)), s]
+                   for r, s in zip(ref, served)]}
+    if control:
+        low = arch.logits_at(seed, model, embed_rows, seqs, rows, fp8=True)
+        out["control_gap"] = [r.max(-1) - r[np.arange(len(r)), c.argmax(-1)]
+                              for r, c in zip(ref, low)]
+    return out
 
 
 # ------------------------------------------------------------ end to end

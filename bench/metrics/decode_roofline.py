@@ -3,8 +3,9 @@ traced window's decode executions, over their device time.
 
 The least time of one decode step at position ``pos`` is the larger of its
 operations over peak bf16 and its bytes (weights, the cache read up to
-``pos``, the entry written) over HBM bandwidth (``bench/flops.py``). Only
-executions tied to a request (``bench/trace.py``) inside the window count.
+``pos``, the entry written) over HBM bandwidth, as the configuration's
+architecture module counts them (``run.arch``). Only executions tied to a
+request (``bench/trace.py``) inside the window count.
 """
 from bench import flops as F
 
@@ -19,7 +20,8 @@ def read(run):
         if ex.kind != "decode" or spec is None or ex.start < lo or ex.end > hi:
             continue
         pos = len(spec.prompt) + ex.ordinal - 1
-        need += F.least_seconds(F.decode_flops(run.model, pos),
-                                F.decode_bytes(run.model, pos), run.peak)
+        need += F.least_seconds(run.arch.decode_flops(run.model, pos),
+                                run.arch.decode_bytes(run.model, pos),
+                                run.peak)
         took += (ex.end - ex.start) * 1e-9
     return 100.0 * need / took if took else None

@@ -1,8 +1,8 @@
 """Whole step: model operations of every prefill and decode execution in the
 traced window, over peak bf16 times the time at least one request was in
-service (``bench/flops.py``: per token the layers' matrix products, the head
-where logits are needed, and attention at that token's context)."""
-from bench import flops as F
+service, as the configuration's architecture module counts them
+(``run.arch``; for the dense decoder, per token the layers' matrix products,
+the head where logits are needed, and attention at that token's context)."""
 from bench import trace as T
 
 
@@ -17,9 +17,10 @@ def read(run):
         if spec is None or ex.start < lo or ex.end > hi:
             continue
         if ex.kind == "prefill":
-            work += F.prefill_flops(run.model, len(spec.prompt))
+            work += run.arch.prefill_flops(run.model, len(spec.prompt))
         elif ex.kind == "decode":
-            work += F.decode_flops(run.model, len(spec.prompt) + ex.ordinal - 1)
+            work += run.arch.decode_flops(
+                run.model, len(spec.prompt) + ex.ordinal - 1)
     if not service or not work:
         return None
     return 100.0 * work / (run.peak["bf16_flops_per_s"] * service)

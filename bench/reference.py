@@ -1,11 +1,11 @@
-"""Plain float32 reference of a dense decoder, layer by layer.
+"""Plain float32 reference of a dense decoder block and its output head.
 
 It follows the published architecture (pre-norm blocks, rotary position
 embedding on the two halves of each head, causal softmax attention, SwiGLU
 feed-forward, output head tied to the embedding) and imports nothing of the
-program: its weights come from :mod:`bench.weights`, drawn again from the
-seed one layer at a time, so that the whole model never sits in float32 on
-the device. Every matrix product runs at ``Precision.HIGHEST``.
+program: ``bench/arch/dense.py`` draws its weights from the seed one layer at
+a time (``bench/weights.py``) and applies :func:`block` and :func:`head`.
+Every matrix product runs at ``Precision.HIGHEST``.
 
 With ``fp8=True`` every matrix product takes its two inputs rounded to
 float8 (e4m3, one scale per tensor) and accumulates in float32: the control,
@@ -14,13 +14,10 @@ one precision step below the bfloat16 the configurations state.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -59,7 +56,7 @@ def _rope(x, theta):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
-def _block(mkey, w, x, fp8):
+def block(mkey, w, x, fp8):
     """One decoder block over a whole sequence x: (T, d)."""
     model = dict(mkey)
     T = x.shape[0]
@@ -83,51 +80,8 @@ def _block(mkey, w, x, fp8):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5))
-def _head(mkey, embed, final_scale, x, rows, fp8):
+def head(mkey, embed, final_scale, x, rows, fp8):
     """Logits (len(rows), vocab) at positions ``rows`` of x."""
     model = dict(mkey)
     h = _norm(model, x[rows], final_scale)
     return _mm("td,vd->tv", h, embed[: model["vocab_size"]], fp8)
-
-
-def logits_at(seed: int, model: dict, embed_rows: int,
-              seqs: Sequence[np.ndarray], rows: Sequence[np.ndarray],
-              fp8: bool = False) -> List[np.ndarray]:
-    """For each token sequence, the float32 logits at the given positions.
-
-    All sequences use the weights of ``seed``; layers are drawn and applied
-    one at a time across every sequence."""
-    mkey = tuple(sorted(model.items()))
-    stem = W.stem_f32(seed, model, embed_rows)
-    xs = [stem["embed"][jnp.asarray(s, jnp.int32)] for s in seqs]
-    for i in range(model["n_layers"]):
-        w = W.layer_f32(seed, model, i)
-        xs = [_block(mkey, w, x, fp8) for x in xs]
-        del w
-    out = [np.asarray(_head(mkey, stem["embed"], stem.get("final_norm/scale"),
-                            x, jnp.asarray(r, jnp.int32), fp8))
-           for x, r in zip(xs, rows)]
-    del stem, xs
-    return out
-
-
-def served_gaps(seed: int, model: dict, embed_rows: int,
-                prompts: Sequence[np.ndarray], served: Sequence[np.ndarray],
-                control: bool = False) -> Dict[str, np.ndarray]:
-    """The reference over each prompt followed by its served tokens.
-
-    Returns, per request, ``gap``: how far each served token's float32 logit
-    lies below the reference's best at its position; with ``control``, also
-    ``control_gap``: the same for the token the float8 control puts first."""
-    seqs = [np.concatenate([p, s[:-1]]).astype(np.int32)
-            for p, s in zip(prompts, served)]
-    rows = [np.arange(len(p) - 1, len(p) - 1 + len(s)) for p, s in
-            zip(prompts, served)]
-    ref = logits_at(seed, model, embed_rows, seqs, rows)
-    out = {"gap": [r.max(-1) - r[np.arange(len(s)), s]
-                   for r, s in zip(ref, served)]}
-    if control:
-        low = logits_at(seed, model, embed_rows, seqs, rows, fp8=True)
-        out["control_gap"] = [r.max(-1) - r[np.arange(len(r)), c.argmax(-1)]
-                              for r, c in zip(ref, low)]
-    return out

@@ -6,7 +6,8 @@
 Run from the root of a checkout, as the only process using the chip(s). The
 cell is looked up in ``BENCHMARK.json``; its configuration, traffic mix and
 per-layer metrics are the files ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``, and the
+``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``, the
+configuration's architecture is ``bench/arch/<bench_arch>.py`` and the
 traffic's arrival kind is ``bench/arrivals/<kind>.py``. Without a TPU, or
 with fewer chips than the cell asks for, it exits 2 and prints no result.
 
@@ -65,6 +66,7 @@ class RunView:
     by_index: dict                 # request index -> mix.Spec
     trace: object = None           # trace.Trace, with --trace 1
     trace_window: Optional[tuple] = None
+    arch: object = None            # the configuration's bench/arch module
 
 
 def read_metric(name: str, view: RunView) -> Optional[float]:
@@ -148,7 +150,8 @@ def run_cell(bench: dict, cell: dict, config: dict, traffic: dict, seed: int,
                 shutil.copy(files[0], keep_trace)
             lo, hi = T.window(tr)
             view = RunView(cell, config["model"], traffic, peak, win,
-                           {s.index: s for s in win.requests}, tr, (lo, hi))
+                           {s.index: s for s in win.requests}, tr, (lo, hi),
+                           cell_obj.arch)
             for m in cell_metrics(bench, cell, "per_layer"):
                 v = read_metric(m["name"], view)
                 if v is not None:

@@ -60,3 +60,27 @@ def test_least_seconds_picks_the_binding_bound():
     # olmo-1b decode at B=1 is bound by memory: about 2.9 ms
     t = F.least_seconds(F.decode_flops(m, 100), F.decode_bytes(m, 100), v5e)
     assert 2.8e-3 < t < 3.0e-3
+
+
+GOLDEN = json.loads((Path(__file__).resolve().parent / "data"
+                     / "dense_golden.json").read_text())["counts"]
+ARCH_COUNTS = ("prefill_flops", "decode_flops", "decode_bytes", "param_count",
+               "weight_bytes")
+
+
+@pytest.mark.parametrize("count", sorted(GOLDEN["olmo-1b"]))
+@pytest.mark.parametrize("name", ["olmo-1b", "deepseek-7b"])
+def test_counts_as_recorded(name, count):
+    """Every count, as bench/flops.py and the dense module give it, equals
+    the one recorded before the architecture seam."""
+    from bench.arch import dense
+
+    m = model(name)["model"]
+    want = GOLDEN[name][count]
+    fns = [getattr(F, count)] + ([getattr(dense, count)]
+                                 if count in ARCH_COUNTS else [])
+    for fn in fns:
+        if isinstance(want, dict):
+            assert {k: fn(m, int(k)) for k in want} == want
+        else:
+            assert fn(m) == want

@@ -1,27 +1,40 @@
 """Seeded weights: the one-call set and the reference's layer-at-a-time
-draws agree bit for bit, and the fingerprint sees a one-element change."""
+draws agree bit for bit, the fingerprint sees a one-element change, and the
+dense decoder's weights are those recorded before the architecture seam."""
+import json
+from pathlib import Path
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from bench import weights as W
+from bench.arch import dense
 from bench.tests import tiny
 
 RMS = dict(tiny.CONFIG["model"], norm="rmsnorm")
+GOLDEN = json.loads((Path(__file__).resolve().parent / "data"
+                     / "dense_golden.json").read_text())
+
+
+def _layout(model):
+    return dense.weight_groups(model, 256)
 
 
 @pytest.mark.parametrize("model", [tiny.CONFIG["model"], RMS],
                          ids=["layernorm", "rmsnorm"])
 def test_layers_and_stem_drawn_alike(model):
     seed = 2 ** 31 + 12345
-    flat = W.make_flat(seed, model, 256)
+    stem, groups = _layout(model)
+    flat = W.make_flat(seed, (stem, groups))
     assert all(v.dtype == jnp.bfloat16 for v in flat.values())
+    (group,) = groups
     for i in range(model["n_layers"]):
-        one = W.layer_f32(seed, model, i)
+        one = W.layer_f32(seed, group, i)
         for name, v in one.items():
             np.testing.assert_array_equal(
                 np.asarray(flat["layers/" + name][i], np.float32), np.asarray(v))
-    for name, v in W.stem_f32(seed, model, 256).items():
+    for name, v in W.stem_f32(seed, stem).items():
         np.testing.assert_array_equal(np.asarray(flat[name], np.float32),
                                       np.asarray(v))
     has_scales = "layers/ln1/scale" in flat
@@ -29,10 +42,10 @@ def test_layers_and_stem_drawn_alike(model):
 
 
 def test_seeds_past_32_bits_differ():
-    m = tiny.CONFIG["model"]
-    a = W.make_flat(2 ** 40 + 5, m, 256)["embed"]
-    b = W.make_flat(5, m, 256)["embed"]
-    c = W.make_flat(2 ** 40 + 5, m, 256)["embed"]
+    layout = _layout(tiny.CONFIG["model"])
+    a = W.make_flat(2 ** 40 + 5, layout)["embed"]
+    b = W.make_flat(5, layout)["embed"]
+    c = W.make_flat(2 ** 40 + 5, layout)["embed"]
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
     with pytest.raises(ValueError):
@@ -40,7 +53,7 @@ def test_seeds_past_32_bits_differ():
 
 
 def test_fingerprint_sees_one_element():
-    flat = W.make_flat(3, tiny.CONFIG["model"], 256)
+    flat = W.make_flat(3, _layout(tiny.CONFIG["model"]))
     want = W.fingerprints(flat)
     host = {k: np.asarray(v) for k, v in flat.items()}
     assert W.fingerprints(host) == want
@@ -59,6 +72,15 @@ def test_fingerprint_sees_one_element():
 
 def test_nest_keeps_parameter_free_norms():
     m = tiny.CONFIG["model"]
-    tree = W.nest(W.make_flat(1, m, 256), m)
+    tree = dense.nest(W.make_flat(1, _layout(m)), m)
     assert tree["final_norm"] == {} and tree["layers"]["ln1"] == {}
     assert set(tree["layers"]["attn"]) == {"wq", "wk", "wv", "wo"}
+
+
+@pytest.mark.parametrize("seed", [7, 2 ** 33 + 7])
+@pytest.mark.parametrize("norm", ["layernorm_nonparametric", "rmsnorm"])
+def test_fingerprints_as_recorded(norm, seed):
+    model = dict(tiny.CONFIG["model"], norm=norm)
+    got = W.fingerprints(W.make_flat(seed, _layout(model)))
+    want = {k: tuple(v) for k, v in GOLDEN["weights"][norm][str(seed)].items()}
+    assert got == want

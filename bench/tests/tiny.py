@@ -9,6 +9,7 @@ CONFIG = {
               "rope_theta": 10000.0, "tie_embeddings": True,
               "dtype": "bfloat16"},
     "program": {"arch": "olmo-1b", "embed_rows": 256, "reduced": True},
+    "bench_arch": "dense",
     "check": {"logit_gap_limit": 0.02},
 }
 

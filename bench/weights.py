@@ -1,22 +1,28 @@
-"""Seeded weights of a dense decoder, made by the benchmark and not the program.
+"""Seeded weights, made by the benchmark and not the program.
 
-One tensor per (leaf, layer) is drawn from its own key,
-``fold_in(fold_in(base(seed), leaf_id), layer)``, so that
-:func:`make_flat` (all layers at once, one jitted call on the device, in
+An architecture module (``bench/arch/``) states the leaves: the stem
+``{leaf: (shape, std)}``, then stacked layer groups ``(prefix, count,
+{leaf: (shape, std)})``. One tensor per (leaf, layer) is drawn from its own
+key, ``fold_in(fold_in(base(seed), leaf_id(prefix + "/" + leaf)), layer)``
+(a stem leaf from ``fold_in(base(seed), leaf_id(leaf))``), so that
+:func:`make_flat` (every group at once, one jitted call on the device, in
 the served dtype) and :func:`layer_f32` (one layer, for the reference) give
-the same numbers. The tree uses the program's store layout: ``embed``,
-``final_norm``, and ``layers`` stacked on a leading axis with ``ln1``,
-``attn/{wq,wk,wv,wo}``, ``ln2`` and ``ffn/{w_gate,w_up,w_down}``.
+the same numbers. Names are the program's store names, a group's leaves
+stacked on a leading axis under ``prefix/``.
 """
 from __future__ import annotations
 
 import functools
-import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+Specs = Dict[str, Tuple[Tuple[int, ...], float]]
+Group = Tuple[str, int, Specs]
+Layout = Tuple[Specs, List[Group]]      # an architecture's weight_groups
+
 
 def base_key(seed: int) -> jax.Array:
     """A key for any whole-number seed, also those past 32 bits."""
@@ -31,44 +37,15 @@ def _leaf_id(name: str) -> int:
     return zlib.crc32(name.encode()) & 0x7FFFFFFF
 
 
-def _has_norm_scales(model: dict) -> bool:
-    return model["norm"] == "rmsnorm"
-
-
-def layer_specs(model: dict) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    """Per-layer leaves: name -> (shape, std). A std of 0 marks a norm scale,
-    drawn as 1 + 0.1 * N(0, 1)."""
-    d, f, L = model["d_model"], model["d_ff"], model["n_layers"]
-    qd = model["n_heads"] * model["head_dim"]
-    kvd = model["n_kv_heads"] * model["head_dim"]
-    specs = {
-        "attn/wq": ((d, qd), 1 / math.sqrt(d)),
-        "attn/wk": ((d, kvd), 1 / math.sqrt(d)),
-        "attn/wv": ((d, kvd), 1 / math.sqrt(d)),
-        "attn/wo": ((qd, d), 1 / math.sqrt(qd * 2 * L)),
-        "ffn/w_gate": ((d, f), 1 / math.sqrt(d)),
-        "ffn/w_up": ((d, f), 1 / math.sqrt(d)),
-        "ffn/w_down": ((f, d), 1 / math.sqrt(f * 2 * L)),
-    }
-    if _has_norm_scales(model):
-        specs["ln1/scale"] = ((d,), 0.0)
-        specs["ln2/scale"] = ((d,), 0.0)
-    return specs
-
-
-def stem_specs(model: dict, embed_rows: int) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    specs = {"embed": ((embed_rows, model["d_model"]), 0.02)}
-    if _has_norm_scales(model):
-        specs["final_norm/scale"] = ((model["d_model"],), 0.0)
-    return specs
-
-
 def _draw(key, shape, std):
+    """A std of 0 marks a norm scale, drawn as 1 + 0.1 * N(0, 1)."""
     z = jax.random.normal(key, shape, jnp.float32)
     return 1.0 + 0.1 * z if std == 0.0 else z * std
 
 
-def _nest(flat: Dict[str, object], empty: Tuple[str, ...]) -> dict:
+def unflatten(flat: Dict[str, object], empty: Tuple[str, ...] = ()) -> dict:
+    """Flat weights as a nested tree; each path of ``empty`` (a node the
+    program keeps without parameters) becomes an empty node."""
     tree: dict = {}
     for path, v in flat.items():
         node = tree
@@ -76,61 +53,62 @@ def _nest(flat: Dict[str, object], empty: Tuple[str, ...]) -> dict:
         for p in parents:
             node = node.setdefault(p, {})
         node[leaf] = v
-    for path in empty:  # parameter-free norms keep their (empty) node
+    for path in empty:
         node = tree
         for p in path.split("/"):
             node = node.setdefault(p, {})
     return tree
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _make(key, lspecs, sspecs, n_layers):
+def _frozen(specs: Specs):
+    return tuple((n, tuple(s), float(std)) for n, (s, std) in specs.items())
+
+
+def _frozen_groups(groups: List[Group]):
+    return tuple((prefix, int(count), _frozen(specs))
+                 for prefix, count, specs in groups)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _make(key, sspecs, groups):
     dtype = jnp.bfloat16
     flat = {}
     for name, shape, std in sspecs:
         flat[name] = _draw(jax.random.fold_in(key, _leaf_id(name)), shape,
                            std).astype(dtype)
-    for name, shape, std in lspecs:
-        k = jax.random.fold_in(key, _leaf_id("layers/" + name))
-        flat["layers/" + name] = jax.vmap(
-            lambda i: _draw(jax.random.fold_in(k, i), shape, std).astype(dtype)
-        )(jnp.arange(n_layers))
+    for prefix, count, lspecs in groups:
+        for name, shape, std in lspecs:
+            k = jax.random.fold_in(key, _leaf_id(prefix + "/" + name))
+            flat[prefix + "/" + name] = jax.vmap(
+                lambda i: _draw(jax.random.fold_in(k, i), shape,
+                                std).astype(dtype)
+            )(jnp.arange(count))
     return flat
 
 
-def _frozen(specs):
-    return tuple((n, tuple(s), float(std)) for n, (s, std) in specs.items())
-
-
-def make_flat(seed: int, model: dict, embed_rows: int) -> Dict[str, jax.Array]:
+def make_flat(seed: int, layout: Layout) -> Dict[str, jax.Array]:
     """Every weight of one model, flat by store name, made on the default
-    device in one jitted call, in bfloat16."""
-    if model["dtype"] != "bfloat16":
-        raise ValueError(f"weights are made in bfloat16, not {model['dtype']}")
-    return _make(base_key(seed), _frozen(layer_specs(model)),
-                 _frozen(stem_specs(model, embed_rows)), model["n_layers"])
+    device in one jitted call, in bfloat16. ``layout`` is what the
+    architecture's ``weight_groups`` returns."""
+    stem, groups = layout
+    return _make(base_key(seed), _frozen(stem), _frozen_groups(groups))
 
 
-def nest(flat: Dict[str, object], model: dict) -> dict:
-    """Flat weights as the program's nested parameter tree."""
-    empty = () if _has_norm_scales(model) else (
-        "final_norm", "layers/ln1", "layers/ln2")
-    return _nest(flat, empty)
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _layer(key, lspecs, i):
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _layer(key, prefix, lspecs, i):
     out = {}
     for name, shape, std in lspecs:
-        k = jax.random.fold_in(key, _leaf_id("layers/" + name))
+        k = jax.random.fold_in(key, _leaf_id(prefix + "/" + name))
         out[name] = _draw(jax.random.fold_in(k, i), shape,
                           std).astype(jnp.bfloat16).astype(jnp.float32)
     return out
 
 
-def layer_f32(seed: int, model: dict, i: int) -> Dict[str, jax.Array]:
-    """Layer ``i``'s weights as :func:`make_flat` makes them, in float32."""
-    return _layer(base_key(seed), _frozen(layer_specs(model)), jnp.int32(i))
+def layer_f32(seed: int, group: Group, i: int) -> Dict[str, jax.Array]:
+    """Layer ``i`` of one group ``(prefix, count, specs)`` as
+    :func:`make_flat` makes it, in float32, keyed by leaf."""
+    prefix, _, specs = group
+    return _layer(base_key(seed), prefix, _frozen(specs), jnp.int32(i))
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -140,10 +118,9 @@ def _stem(key, sspecs):
             for name, shape, std in sspecs}
 
 
-def stem_f32(seed: int, model: dict, embed_rows: int) -> Dict[str, jax.Array]:
-    """Embedding (and final norm scale) as :func:`make_flat` makes them, in
-    float32."""
-    return _stem(base_key(seed), _frozen(stem_specs(model, embed_rows)))
+def stem_f32(seed: int, stem: Specs) -> Dict[str, jax.Array]:
+    """The stem leaves as :func:`make_flat` makes them, in float32."""
+    return _stem(base_key(seed), _frozen(stem))
 
 
 @jax.jit
