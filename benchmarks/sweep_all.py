@@ -2,7 +2,7 @@
 import itertools, os, subprocess, sys
 
 ARCHS = ["deepseek-7b", "jamba-1.5-large-398b", "llama-3.2-vision-90b",
-         "mamba2-370m", "mistral-nemo-12b", "moonshot-v1-16b-a3b",
+         "mamba2-370m", "mistral-nemo-12b", "moonlight-16b-a3b",
          "olmo-1b", "qwen1.5-110b", "qwen3-moe-30b-a3b",
          "seamless-m4t-large-v2"]
 SHAPES = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]

@@ -41,13 +41,24 @@ class ModelConfig:
     norm_type: str = "rmsnorm"            # "rmsnorm" | "layernorm" | "nonparametric_ln" (olmo)
     norm_eps: float = 1e-5
     # MoE --------------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0                    # routed experts: the router's width
+    held_experts: int = 0                 # experts whose weights are here (0: all);
+    first_held_expert: int = 0            # ... from this id (one chip's expert share)
     top_k: int = 0
     n_shared_experts: int = 0
+    router_score: str = "softmax"         # "softmax" | "sigmoid" (deepseek-v3 noaux_tc)
+    routed_scaling: float = 1.0           # routed output scale (deepseek-v3)
+    first_k_dense: int = 0                # leading dense layers before the MoE stack
+    d_ff_dense: int = 0                   # their SwiGLU width
     moe_every: int = 1                    # apply MoE every Nth layer (jamba: 2)
     capacity_factor: float = 1.25
     moe_impl: str = "capacity"            # "capacity" | "ragged"
     router_aux_coef: float = 0.01
+    # multi-head latent attention (deepseek-v2/v3); off while kv_lora_rank is 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # hybrid / SSM -----------------------------------------------------------
     attn_every: int = 0                   # jamba: 1 attention layer per `attn_every` layers (8)
     d_state: int = 0                      # mamba2 SSM state dim
@@ -86,6 +97,10 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.held_experts or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -129,7 +144,15 @@ class ModelConfig:
             remat_policy="none",
         )
         if self.n_experts:
-            kw.update(n_experts=min(8, self.n_experts), top_k=min(2, self.top_k))
+            e = min(8, self.n_experts)
+            kw.update(n_experts=e, top_k=min(2, self.top_k),
+                      held_experts=self.held_experts * e // self.n_experts,
+                      first_held_expert=self.first_held_expert * e // self.n_experts)
+        if self.first_k_dense:
+            kw.update(d_ff_dense=256)
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                      v_head_dim=16)
         if self.family in (HYBRID,):
             kw.update(n_layers=self.attn_every or 8, d_state=16, ssm_headdim=16,
                       ssm_chunk=16, expand=2)
@@ -148,10 +171,17 @@ class ModelConfig:
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.qkv_bias:
             attn += self.q_dim + 2 * self.kv_dim
+        if self.kv_lora_rank:  # MLA: wq, wkv_a, kv_norm, wkv_b, wo
+            H, r, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            nope, vd = self.qk_nope_head_dim, self.v_head_dim
+            attn = (d * H * (nope + rope) + d * (r + rope) + r
+                    + r * H * (nope + vd) + H * vd * d)
         ffn_dense = 3 * d * self.d_ff  # SwiGLU: gate, up, down
         moe = 0
         if self.n_experts:
-            moe = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
+            moe = self.n_held_experts * 3 * d * self.d_ff + d * self.n_experts
+            if self.router_score == "sigmoid":   # the selection bias
+                moe += self.n_experts
             if self.n_shared_experts:
                 moe += self.n_shared_experts * 3 * d * self.d_ff
         norm = 2 * d if self.norm_type == "rmsnorm" else (0 if self.norm_type == "nonparametric_ln" else 4 * d)
@@ -178,7 +208,9 @@ class ModelConfig:
         if self.family in (DENSE,):
             total += self.n_layers * layer_cost("attn", False)
         elif self.family == MOE:
-            total += self.n_layers * layer_cost("attn", True)
+            k = self.first_k_dense
+            total += (self.n_layers - k) * layer_cost("attn", True)
+            total += k * (layer_cost("attn", False) + 3 * d * (self.d_ff_dense - self.d_ff))
         elif self.family == SSM:
             # mamba2 block has no separate FFN
             total += self.n_layers * (ssm + d)
@@ -204,16 +236,16 @@ class ModelConfig:
         if not self.n_experts:
             return self.param_count()
         full = self.param_count()
-        expert_params = self.n_experts * 3 * self.d_model * self.d_ff
+        expert_params = self.n_held_experts * 3 * self.d_model * self.d_ff
         active_expert = (self.top_k + self.n_shared_experts) * 3 * self.d_model * self.d_ff
-        n_moe_layers = self._n_moe_layers()
-        return full - n_moe_layers * (expert_params - active_expert)
+        return full - self.n_moe_layers * (expert_params - active_expert)
 
-    def _n_moe_layers(self) -> int:
+    @property
+    def n_moe_layers(self) -> int:
         if not self.n_experts:
             return 0
         if self.family == MOE:
-            return self.n_layers
+            return self.n_layers - self.first_k_dense
         if self.family == HYBRID:
             period = self.attn_every
             per_period = sum(1 for i in range(period)

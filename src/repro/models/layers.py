@@ -136,9 +136,10 @@ def qkv_project(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
 
 def _mha_chunk(q, k, v, *, causal: bool, q_offset, kv_len: Optional[jnp.ndarray]):
-    """One dense attention block: q (B,Sq,H,hd), k/v (B,Skv,G,hd) pre-broadcast.
+    """One dense attention block: q (B,Sq,H,hd), k (B,Skv,G,hd), v
+    (B,Skv,G,vd) pre-broadcast.
 
-    Returns (B, Sq, H, hd). fp32 softmax. ``kv_len`` masks a padded KV cache.
+    Returns (B, Sq, H, vd). fp32 softmax. ``kv_len`` masks a padded KV cache.
     """
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
@@ -157,7 +158,7 @@ def _mha_chunk(q, k, v, *, causal: bool, q_offset, kv_len: Optional[jnp.ndarray]
         scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgh->bqgrh", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool = True,
@@ -165,7 +166,7 @@ def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool = True,
     """Memory-bounded attention: scan over query chunks (flash algorithm
     shape-wise; per-chunk softmax is exact since the full KV row is visible).
 
-    q: (B,S,H,hd); k,v: (B,T,G,hd). Returns (B,S,H,hd).
+    q: (B,S,H,hd); k: (B,T,G,hd); v: (B,T,G,vd). Returns (B,S,H,vd).
     """
     if cfg.use_pallas:
         from repro.kernels import ops as kops
@@ -186,7 +187,7 @@ def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool = True,
         return carry, out
 
     _, outs = jax.lax.scan(body, None, (jnp.arange(n_chunks), jnp.moveaxis(qc, 1, 0)))
-    return jnp.moveaxis(outs, 0, 1).reshape(B, S, H, hd)
+    return jnp.moveaxis(outs, 0, 1).reshape(B, S, H, v.shape[-1])
 
 
 def decode_attention_core(cfg: ModelConfig, q, k_cache, v_cache, kv_len) -> jnp.ndarray:

@@ -8,6 +8,7 @@ Entry points (all pure functions over (cfg, params, ...)):
   init_cache(cfg, batch, max_len)        -> decode cache pytree (zeros)
   prefill(cfg, params, batch, max_len)   -> (logits, cache)
   decode_step(cfg, params, cache, token, pos) -> (logits, cache)
+                          [moe_counts=True: (logits, cache, counts)]
   decode_step_rows(cfg, params, caches, tokens, positions)
                                          -> (logits, caches)  [dense rows]
 
@@ -16,7 +17,11 @@ optional "frontend": (B, S_src, D) precomputed modality embeddings (vlm/audio)}.
 
 Layers are stacked along a leading axis and iterated with ``lax.scan``
 (MaxText-style) so HLO stays compact for 100-layer models; bodies are wrapped
-in ``jax.checkpoint`` per ``cfg.remat_policy``.
+in ``jax.checkpoint`` per ``cfg.remat_policy``. A MoE model with leading
+dense layers (``first_k_dense``) has two stacks, ``dense_layers`` then
+``layers``, each scanned in turn. Attention is GQA, or MLA
+(``models/mla.py``) where ``cfg.kv_lora_rank`` is set; an untied model reads
+its logits from ``lm_head``.
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ from repro.configs.base import (
     ModelConfig, DENSE, MOE, HYBRID, SSM, ENCDEC, VLM,
 )
 from repro.models import layers as L
+from repro.models import mla as A
 from repro.models import moe as M
 from repro.models import mamba as S
 from repro.models import partitioning as part
@@ -54,12 +60,22 @@ def _maybe_remat(cfg: ModelConfig, fn):
 # per-layer init
 # ---------------------------------------------------------------------------
 
-def _init_attn_layer(cfg: ModelConfig, key, use_moe: bool) -> Params:
+def _init_attn_layer(cfg: ModelConfig, key, use_moe: bool,
+                     d_ff: Optional[int] = None) -> Params:
     k1, k2 = jax.random.split(key)
-    p = {"ln1": L.init_norm(cfg), "attn": L.init_attention(cfg, k1),
-         "ln2": L.init_norm(cfg)}
-    p["ffn"] = M.init_moe(cfg, k2) if use_moe else L.init_mlp(cfg, k2)
+    attn = A.init_mla(cfg, k1) if cfg.kv_lora_rank else L.init_attention(cfg, k1)
+    p = {"ln1": L.init_norm(cfg), "attn": attn, "ln2": L.init_norm(cfg)}
+    p["ffn"] = M.init_moe(cfg, k2) if use_moe else L.init_mlp(cfg, k2, d_ff)
     return p
+
+
+def _stacks(cfg: ModelConfig) -> Tuple[Tuple[str, str, int], ...]:
+    """The attention-family trunk's layer stacks, in order: (params key,
+    cache key, layers). Leading dense layers stack apart from the rest."""
+    if cfg.first_k_dense:
+        return (("dense_layers", "dense_attn", cfg.first_k_dense),
+                ("layers", "attn", cfg.n_layers - cfg.first_k_dense))
+    return (("layers", "attn", cfg.n_layers),)
 
 
 def _init_mamba_layer(cfg: ModelConfig, key, with_ffn: bool, use_moe: bool) -> Params:
@@ -92,9 +108,12 @@ def _stack(init_fn, key, n: int):
 def _apply_attn_layer(cfg: ModelConfig, p: Params, x, positions, *,
                       causal=True, rope=True, kv_out=False):
     h = L.apply_norm(cfg, p["ln1"], x)
-    q, k, v = L.qkv_project(cfg, p["attn"], h, positions, rope=rope)
-    o = L.attention_core(cfg, q, k, v, causal=causal)
-    x = x + L.attention_out(cfg, p["attn"], o)
+    if cfg.kv_lora_rank:
+        x = x + A.attend(cfg, p["attn"], h, positions)
+    else:
+        q, k, v = L.qkv_project(cfg, p["attn"], h, positions, rope=rope)
+        o = L.attention_core(cfg, q, k, v, causal=causal)
+        x = x + L.attention_out(cfg, p["attn"], o)
     h = L.apply_norm(cfg, p["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
     if isinstance(p["ffn"], dict) and "router" in p["ffn"]:
@@ -167,11 +186,19 @@ def init_params(cfg: ModelConfig, key) -> Params:
         "embed": L.embed_init(keys[0], (cfg.padded_vocab, cfg.d_model), cfg.pdtype),
         "final_norm": L.init_norm(cfg),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = L.embed_init(keys[7], (cfg.padded_vocab, cfg.d_model),
+                                         cfg.pdtype)
     fam = cfg.family
     if fam in (DENSE, MOE):
+        if cfg.first_k_dense:
+            params["dense_layers"] = _stack(
+                lambda k: _init_attn_layer(cfg, k, False, cfg.d_ff_dense),
+                keys[2], cfg.first_k_dense)
         use_moe = cfg.n_experts > 0
         params["layers"] = _stack(
-            lambda k: _init_attn_layer(cfg, k, use_moe), keys[1], cfg.n_layers)
+            lambda k: _init_attn_layer(cfg, k, use_moe), keys[1],
+            cfg.n_layers - cfg.first_k_dense)
     elif fam == SSM:
         params["layers"] = _stack(
             lambda k: _init_mamba_layer(cfg, k, with_ffn=False, use_moe=False),
@@ -229,8 +256,9 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 def _logits(cfg: ModelConfig, params: Params, x) -> jnp.ndarray:
     x = L.apply_norm(cfg, params["final_norm"], x)
+    head = params["embed" if cfg.tie_embeddings else "lm_head"]
     logits = part.shard_logits(
-        jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype)))
+        jnp.einsum("bsd,vd->bsv", x, head.astype(x.dtype)))
     if cfg.padded_vocab != cfg.vocab_size:  # mask Megatron-style vocab pad
         iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
         logits = jnp.where(iota < cfg.vocab_size, logits, -1e30)
@@ -264,7 +292,9 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jnp.ndarray]
             x, a = _apply_attn_layer(cfg, layer, x, positions)
             return (x, aux + a), None
 
-        (x, aux), _ = jax.lax.scan(_maybe_remat(cfg, body), (x, aux0), params["layers"])
+        aux = aux0
+        for pk, _, _ in _stacks(cfg):
+            (x, aux), _ = jax.lax.scan(_maybe_remat(cfg, body), (x, aux), params[pk])
     elif fam == SSM:
         def body(carry, layer):
             x, aux = carry
@@ -392,6 +422,8 @@ def loss_fn(cfg: ModelConfig, params: Params, batch
 # ---------------------------------------------------------------------------
 
 def _attn_cache_zeros(cfg: ModelConfig, B: int, T: int):
+    if cfg.kv_lora_rank:
+        return A.cache_zeros(cfg, B, T)
     shape = (B, T, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.cdtype), "v": jnp.zeros(shape, cfg.cdtype)}
 
@@ -412,7 +444,8 @@ def init_cache(cfg: ModelConfig, B: int, max_len: int,
             jax.tree.map(lambda x: x[None], fn())
 
     if fam in (DENSE, MOE):
-        return {"attn": stacked(lambda: _attn_cache_zeros(cfg, B, max_len), cfg.n_layers)}
+        return {ck: stacked(lambda: _attn_cache_zeros(cfg, B, max_len), n)
+                for _, ck, n in _stacks(cfg)}
     if fam == SSM:
         return {"mamba": stacked(lambda: _mamba_cache_zeros(cfg, B), cfg.n_layers)}
     if fam == HYBRID:
@@ -470,15 +503,20 @@ def prefill_attn_layer(cfg: ModelConfig, layer: Params, cl: Params,
     per-layer path (DESIGN.md §9) call this exact function, so streamed
     generation is mathematically identical to the batch path."""
     h = L.apply_norm(cfg, layer["ln1"], x)
-    q, k, v = L.qkv_project(cfg, layer["attn"], h, positions)
-    o = L.attention_core(cfg, q, k, v, causal=True)
-    x = x + L.attention_out(cfg, layer["attn"], o)
+    if cfg.kv_lora_rank:
+        o, new = A.prefill(cfg, layer["attn"], h, positions)
+        x, write = x + o, A.write
+    else:
+        q, k, v = L.qkv_project(cfg, layer["attn"], h, positions)
+        o = L.attention_core(cfg, q, k, v, causal=True)
+        x = x + L.attention_out(cfg, layer["attn"], o)
+        new, write = (k, v), _write_kv
     h = L.apply_norm(cfg, layer["ln2"], x)
     if "router" in layer["ffn"]:
         f, _ = M.apply_moe(cfg, layer["ffn"], h)
     else:
         f = L.apply_mlp(layer["ffn"], h)
-    return _res(cfg, x + f), _write_kv(cl, k, v, 0)
+    return _res(cfg, x + f), write(cl, *new, 0)
 
 
 def prefill(cfg: ModelConfig, params: Params, batch, max_len: int
@@ -498,8 +536,10 @@ def prefill(cfg: ModelConfig, params: Params, batch, max_len: int
             layer, cl = xs
             return prefill_attn_layer(cfg, layer, cl, x, positions)
 
-        x, attn_cache = jax.lax.scan(body, x, (params["layers"], cache["attn"]))
-        cache = {"attn": attn_cache}
+        primed = {}
+        for pk, ck, _ in _stacks(cfg):
+            x, primed[ck] = jax.lax.scan(body, x, (params[pk], cache[ck]))
+        cache = primed
     elif fam == SSM:
         def body(x, xs):
             layer, cl = xs
@@ -609,17 +649,26 @@ def prefill(cfg: ModelConfig, params: Params, batch, max_len: int
 # decode step
 # ---------------------------------------------------------------------------
 
-def _attn_decode(cfg: ModelConfig, p: Params, x, cl, pos, *, rope=True):
-    """x: (B,1,D); cl: one layer's KV cache. Returns (x, new_cache)."""
+def _attn_decode(cfg: ModelConfig, p: Params, x, cl, pos, *, rope=True,
+                 counts=False):
+    """x: (B,1,D); cl: one layer's KV cache. Returns (x, new_cache), and
+    with ``counts`` the MoE layer's routing counts after them."""
     B = x.shape[0]
     h = L.apply_norm(cfg, p["ln1"], x)
-    positions = jnp.full((1, 1), pos, jnp.int32)
-    q, k, v = L.qkv_project(cfg, p["attn"], h, positions, rope=rope)
-    cl = _write_kv(cl, k, v, pos)
-    kv_len = jnp.full((B,), pos + 1, jnp.int32)
-    o = L.decode_attention_core(cfg, q, cl["k"], cl["v"], kv_len)
-    x = x + L.attention_out(cfg, p["attn"], o)
+    if cfg.kv_lora_rank:
+        o, cl = A.decode(cfg, p["attn"], h, cl, pos)
+        x = x + o
+    else:
+        positions = jnp.full((1, 1), pos, jnp.int32)
+        q, k, v = L.qkv_project(cfg, p["attn"], h, positions, rope=rope)
+        cl = _write_kv(cl, k, v, pos)
+        kv_len = jnp.full((B,), pos + 1, jnp.int32)
+        o = L.decode_attention_core(cfg, q, cl["k"], cl["v"], kv_len)
+        x = x + L.attention_out(cfg, p["attn"], o)
     h = L.apply_norm(cfg, p["ln2"], x)
+    if counts:
+        f, _, c = M.apply_moe(cfg, p["ffn"], h, counts=True)
+        return x + f, cl, c
     if "router" in p["ffn"]:
         f, _ = M.apply_moe(cfg, p["ffn"], h)
     else:
@@ -642,11 +691,12 @@ def _mamba_decode(cfg: ModelConfig, p: Params, x, cl):
 
 
 def decode_step(cfg: ModelConfig, params: Params, cache: Params,
-                token: jnp.ndarray, pos: jnp.ndarray
-                ) -> Tuple[jnp.ndarray, Params]:
+                token: jnp.ndarray, pos: jnp.ndarray, moe_counts: bool = False):
     """One decode step. token: (B,) int32; pos: scalar int32 (cache length so far).
 
-    Returns (logits (B, V), new_cache).
+    Returns (logits (B, V), new_cache); with ``moe_counts`` (MoE models)
+    also (2,) int32, summed over the MoE layers: held experts that took a
+    token, and assignments that landed on held experts.
     """
     fam = cfg.family
     x = part.shard_btd(params["embed"][token][:, None, :].astype(cfg.cdtype))  # (B,1,D)
@@ -657,8 +707,19 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
             x, ncl = _attn_decode(cfg, layer, x, cl, pos)
             return x, ncl
 
-        x, new_attn = jax.lax.scan(body, x, (params["layers"], cache["attn"]))
-        new_cache = {"attn": new_attn}
+        def counted(x, xs):
+            layer, cl = xs
+            x, ncl, c = _attn_decode(cfg, layer, x, cl, pos, counts=True)
+            return x, (ncl, c)
+
+        new_cache = {}
+        for pk, ck, _ in _stacks(cfg):
+            if moe_counts and pk == "layers":
+                x, (new_cache[ck], c) = jax.lax.scan(
+                    counted, x, (params[pk], cache[ck]))
+                counts = jnp.sum(c, axis=0)
+            else:
+                x, new_cache[ck] = jax.lax.scan(body, x, (params[pk], cache[ck]))
     elif fam == SSM:
         def body(x, xs):
             layer, cl = xs
@@ -733,6 +794,8 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
         raise ValueError(fam)
 
     logits = _logits(cfg, params, x)
+    if moe_counts:
+        return logits[:, 0], new_cache, counts
     return logits[:, 0], new_cache
 
 
@@ -747,12 +810,17 @@ def decode_step_rows(cfg: ModelConfig, params: Params, caches, tokens,
     RoPE, the KV write and attention run per row, against that row's cache
     alone. Row r's result depends only on row r's inputs.
 
-    Returns (logits (k, V), the k new caches). Dense only: an MoE layer's
-    expert capacity can couple the rows of one batch.
+    Returns (logits (k, V), the k new caches). Dense GQA only: an MoE
+    layer's expert capacity can couple the rows of one batch, and an MLA
+    layer's latent cache has no per-row path here.
     """
     if cfg.family != DENSE or cfg.n_experts:
         raise ValueError(f"decode_step_rows serves dense layers, not "
-                         f"{cfg.family!r} with {cfg.n_experts} experts")
+                         f"{cfg.family!r} with {cfg.n_experts} experts: an "
+                         f"MoE layer's routing can couple the rows")
+    if cfg.kv_lora_rank or cfg.first_k_dense:
+        raise ValueError(f"decode_step_rows has no per-row path for MLA's "
+                         f"latent cache or a mixed layout ({cfg.name})")
     k = len(caches)
     x = part.shard_btd(params["embed"][tokens][:, None, :].astype(cfg.cdtype))
 

@@ -8,7 +8,14 @@
                 candidate on TPU.
 
 Router: softmax over expert logits, top-k, renormalized combine weights, plus
-the standard load-balancing auxiliary loss (Switch Transformer eq. 4).
+the standard load-balancing auxiliary loss (Switch Transformer eq. 4). Or, as
+DeepSeek-V3 (``router_score="sigmoid"``, ``noaux_tc`` with one group):
+float32 logits, sigmoid scores, top-k chosen on score plus a bias, weighted by
+the unbiased scores, renormalized and scaled by ``routed_scaling``.
+
+An expert layer may hold one chip's share of the routed experts
+(``held_experts`` from ``first_held_expert``): it routes over all of them and
+adds only its own experts' part of the result (the ``ragged`` path).
 """
 from __future__ import annotations
 
@@ -27,9 +34,9 @@ Params = Dict[str, jnp.ndarray]
 
 def init_moe(cfg: ModelConfig, key) -> Params:
     ks = jax.random.split(key, 5)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held_experts
     p: Params = {
-        "router": dense_init(ks[0], (d, e), jnp.float32, scale=0.02),
+        "router": dense_init(ks[0], (d, cfg.n_experts), jnp.float32, scale=0.02),
         "w_gate": dense_init(ks[1], (e, d, f), cfg.pdtype),
         "w_up": dense_init(ks[2], (e, d, f), cfg.pdtype),
         "w_down": dense_init(ks[3], (e, f, d), cfg.pdtype,
@@ -41,6 +48,8 @@ def init_moe(cfg: ModelConfig, key) -> Params:
         p["shared_gate"] = dense_init(kk[0], (d, sf), cfg.pdtype)
         p["shared_up"] = dense_init(kk[1], (d, sf), cfg.pdtype)
         p["shared_down"] = dense_init(kk[2], (sf, d), cfg.pdtype)
+    if cfg.router_score == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     return p
 
 
@@ -51,6 +60,8 @@ def router_topk(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
     Router matmul keeps x in bf16 with fp32 ACCUMULATION: upcasting the
     input would make XLA hoist the fp32 convert above the sequence-parallel
     all-gather and ship 2x the bytes (measured on qwen3-moe train)."""
+    if cfg.router_score == "sigmoid":
+        return _router_sigmoid(cfg, p, x2d)
     # bf16 dot + post-hoc fp32 cast: fp32 ACCUMULATION here would make the
     # VJP emit fp32 cotangents for x, doubling every sequence-parallel
     # boundary collective in the backward pass (measured on qwen3-moe)
@@ -65,6 +76,25 @@ def router_topk(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
     mean_prob = jnp.mean(probs, axis=0)                          # (E,)
     aux = E * jnp.sum(frac * mean_prob)
     return topw.astype(x2d.dtype), topi, aux
+
+
+def _router_sigmoid(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """DeepSeek-V3 ``noaux_tc`` routing with one group: logits in float32 as
+    the published gate computes them, experts chosen on sigmoid score plus
+    the selection bias, weighted by the unbiased scores."""
+    logits = jnp.dot(x2d.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)                              # (T,E)
+    choice = scores + p["router_bias"].astype(jnp.float32)
+    _, topi = jax.lax.top_k(choice, cfg.top_k)
+    topw = jnp.take_along_axis(scores, topi, axis=-1)
+    topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
+    topw = topw * cfg.routed_scaling
+    E = cfg.n_experts
+    frac = jnp.mean(jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32), 1), 0)
+    mean_p = jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=0)
+    return topw.astype(x2d.dtype), topi, E * jnp.sum(frac * mean_p)
 
 
 def _expert_ffn_batched(p: Params, xe: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -114,29 +144,39 @@ def moe_capacity(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
 
 
 def moe_ragged(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sort-by-expert + ragged_dot grouped GEMM. No token drops."""
-    T, D = x2d.shape
-    E, K = cfg.n_experts, cfg.top_k
-    topw, topi, aux = router_topk(cfg, p, x2d)
+               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Sort-by-expert + ragged_dot grouped GEMM. No token drops.
 
-    e_flat = topi.reshape(-1)
-    tok_flat = jnp.repeat(jnp.arange(T), K)
-    w_flat = topw.reshape(-1)
-    order = jnp.argsort(e_flat)
-    xs = x2d[tok_flat[order]]                                    # (T*K, D)
-    group_sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
+    Only assignments to the held experts are computed: the others sort
+    last, outside every group, and add nothing. Returns (out, aux, counts):
+    counts (2,) int32 are the held experts that took at least one token and
+    the assignments that landed on held experts."""
+    T, D = x2d.shape
+    n, K = cfg.n_held_experts, cfg.top_k
+    with jax.named_scope("moe.route"):
+        topw, topi, aux = router_topk(cfg, p, x2d)
+        e_flat = topi.reshape(-1) - cfg.first_held_expert
+        held = (e_flat >= 0) & (e_flat < n)
+        e_flat = jnp.where(held, e_flat, n)
+        tok_flat = jnp.repeat(jnp.arange(T), K)
+        w_flat = topw.reshape(-1)
+        order = jnp.argsort(e_flat)
+        xs = x2d[tok_flat[order]]                                # (T*K, D)
+        group_sizes = jnp.sum(jax.nn.one_hot(e_flat, n, dtype=jnp.int32),
+                              axis=0)                            # (n,)
 
     dt = x2d.dtype
-    g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), group_sizes))
-    u = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), group_sizes)
-    ys = jax.lax.ragged_dot(g * u, p["w_down"].astype(dt), group_sizes)
-    ys = ys * w_flat[order][:, None]
-
-    out = jnp.zeros((T, D), dt).at[tok_flat[order]].add(ys)
+    with jax.named_scope("moe.experts"):
+        g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), group_sizes))
+        u = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), group_sizes)
+        ys = jax.lax.ragged_dot(g * u, p["w_down"].astype(dt), group_sizes)
+        ys = jnp.where(held[order][:, None], ys * w_flat[order][:, None], 0)
+        out = jnp.zeros((T, D), dt).at[tok_flat[order]].add(ys)
     if cfg.n_shared_experts:
-        out = out + _shared_ffn(p, x2d)
-    return out, aux
+        with jax.named_scope("moe.shared"):
+            out = out + _shared_ffn(p, x2d)
+    counts = jnp.stack([jnp.sum(group_sizes > 0), jnp.sum(group_sizes)])
+    return out, aux, counts.astype(jnp.int32)
 
 
 def _shared_ffn(p: Params, x2d: jnp.ndarray) -> jnp.ndarray:
@@ -287,13 +327,17 @@ def moe_ep_shardmap(cfg: ModelConfig, p: Params, x: jnp.ndarray
     return out, aux
 
 
-def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) -> (out, aux_loss)."""
+def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray, counts: bool = False):
+    """x: (B, S, D) -> (out, aux_loss), and with ``counts`` the ragged
+    path's (2,) routing counts (``moe_ragged``) after them."""
     B, S, D = x.shape
     if cfg.moe_impl == "ragged":
-        out, aux = moe_ragged(cfg, p, x.reshape(B * S, D))
-        return out.reshape(B, S, D), aux
+        out, aux, c = moe_ragged(cfg, p, x.reshape(B * S, D))
+        return (out.reshape(B, S, D), aux) + ((c,) if counts else ())
+    if (cfg.router_score != "softmax" or cfg.n_held_experts != cfg.n_experts
+            or counts):
+        raise ValueError(f"moe_impl {cfg.moe_impl!r} routes by softmax over "
+                         f"every expert it holds; {cfg.name} needs 'ragged'")
     if cfg.moe_impl == "grouped":
         return moe_capacity_grouped(cfg, p, x)
     if cfg.moe_impl == "ep":

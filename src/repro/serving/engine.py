@@ -62,6 +62,23 @@ def _prefill_batch(cfg: ModelConfig, tokens: np.ndarray) -> Dict[str, Any]:
     return batch
 
 
+def _streamable(cfg: ModelConfig) -> bool:
+    """Whether the layer-streaming path serves ``cfg``: one stack of GQA
+    layers (a mixed layout or MLA takes the batch open)."""
+    return (cfg.family in ("dense", "moe") and not cfg.first_k_dense
+            and not cfg.kv_lora_rank)
+
+
+def _moe_stats(cfg: ModelConfig, B: int, counts: np.ndarray) -> Dict[str, float]:
+    """``engine.decode`` stats from a request's per-step MoE counts (steps,
+    2): held experts that took a token, per MoE layer and step, and the
+    share of routed assignments that landed on held experts."""
+    layer_steps = counts.shape[0] * cfg.n_moe_layers
+    return {"experts_touched": float(counts[:, 0].sum()) / layer_steps,
+            "held_share": float(counts[:, 1].sum())
+            / (layer_steps * cfg.top_k * B)}
+
+
 def _rows_step(cfg: ModelConfig, params, caches, toks, positions):
     """One decode step over B=1 rows of one model: each row's next token
     (1,) and new cache."""
@@ -248,7 +265,9 @@ class InferenceEngine:
         if kind == "prefill":
             exe = jax.jit(lambda p, b: M.prefill(cfg, p, b, max_len))
         elif kind == "decode":
-            exe = jax.jit(lambda p, c, t, pos: M.decode_step(cfg, p, c, t, pos))
+            counted = cfg.n_experts > 0 and cfg.moe_impl == "ragged"
+            exe = jax.jit(lambda p, c, t, pos: M.decode_step(
+                cfg, p, c, t, pos, moe_counts=counted))
         elif kind == "sembed":
             exe = jax.jit(lambda p, t: M.stream_prefill_embed(cfg, p, t))
         elif kind == "slayer":
@@ -360,19 +379,25 @@ class InferenceEngine:
             row = _Row(sm.key, arch_signature(sm.cfg), max_len, sm.params,
                        (sig_d, exe_d), cache, S, max_new_tokens - 1, out)
             cache = None    # the row's steps free each cache they replace
-        with spans.span("engine.decode", req=req, steps=max_new_tokens - 1):
+        with spans.span("engine.decode", req=req,
+                        steps=max_new_tokens - 1) as sp:
+            counts = []     # MoE steps' routing counts, left on the device
             if row is not None:
                 extra_c += self._decode_row(row, req)
             else:
                 for i in range(max_new_tokens - 1):
                     with spans.span("engine.step", req=req, rows=1):
-                        (logits, cache), dc = self._run_exe(
+                        (logits, cache, *c), dc = self._run_exe(
                             sig_d, exe_d, sm.params, cache, tok,
                             jnp.int32(S + i))
                     extra_c += dc
+                    counts += c
                     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     out.append(tok)
             result = np.asarray(jnp.stack(out, axis=1))
+            if counts and spans.tracing():      # one read, after the loop
+                sp.set_metadata(**_moe_stats(
+                    sm.cfg, B, np.stack(jax.device_get(counts))))
         compute_s = max(0.0, time.perf_counter() - t0 - extra_c)
 
         tm = sm.loaded.timings
@@ -522,7 +547,7 @@ class InferenceEngine:
             cfg = self._config_for(key)
         if cfg is None and not self._fetchable(key):
             return None
-        if cfg is not None and cfg.family not in ("dense", "moe"):
+        if cfg is not None and not _streamable(cfg):
             return None
         from repro.core.cache import Tier
         if self.mrm.resident(key, Tier.DEVICE) or \
@@ -539,7 +564,7 @@ class InferenceEngine:
             if raw is not None:
                 cfg = ModelConfig(**dict(raw))
                 self._cfg_cache[(name, version)] = cfg
-        if fut.plan is None or cfg is None or cfg.family not in ("dense", "moe"):
+        if fut.plan is None or cfg is None or not _streamable(cfg):
             # warm hit / non-streaming primary / unknown config: batch path
             # (the close below just drops our reference; bytes stay cached)
             h = fut.result()

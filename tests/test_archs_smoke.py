@@ -97,10 +97,9 @@ def test_param_count_matches_init():
 def test_full_config_param_counts_sane():
     """Full (non-reduced) configs land near their advertised sizes."""
     expect = {
-        # NOTE: assignment-spec configs are the source of truth, not the
-        # marketing names — 48L x 64e x d_ff 1408 gives ~27.7B total
-        # (active ~3B matches the "a3b" tag).
-        "moonshot-v1-16b-a3b": (20e9, 32e9),
+        # the published total, 15.96B: MLA, 1 dense + 26 MoE layers of
+        # 64 experts of 1408, 2 shared, untied head
+        "moonlight-16b-a3b": (15.5e9, 16.5e9),
         "qwen3-moe-30b-a3b": (24e9, 36e9),
         "llama-3.2-vision-90b": (70e9, 105e9),
         "mistral-nemo-12b": (10e9, 14.5e9),
