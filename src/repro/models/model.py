@@ -650,9 +650,10 @@ def prefill(cfg: ModelConfig, params: Params, batch, max_len: int
 # ---------------------------------------------------------------------------
 
 def _attn_decode(cfg: ModelConfig, p: Params, x, cl, pos, *, rope=True,
-                 counts=False):
+                 counts=False, layer=None):
     """x: (B,1,D); cl: one layer's KV cache. Returns (x, new_cache), and
-    with ``counts`` the MoE layer's routing counts after them."""
+    with ``counts`` the MoE layer's routing counts after them. ``layer``:
+    p's expert banks are the whole stack's, this layer's at that index."""
     B = x.shape[0]
     h = L.apply_norm(cfg, p["ln1"], x)
     if cfg.kv_lora_rank:
@@ -667,10 +668,10 @@ def _attn_decode(cfg: ModelConfig, p: Params, x, cl, pos, *, rope=True,
         x = x + L.attention_out(cfg, p["attn"], o)
     h = L.apply_norm(cfg, p["ln2"], x)
     if counts:
-        f, _, c = M.apply_moe(cfg, p["ffn"], h, counts=True)
+        f, _, c = M.apply_moe(cfg, p["ffn"], h, counts=True, layer=layer)
         return x + f, cl, c
     if "router" in p["ffn"]:
-        f, _ = M.apply_moe(cfg, p["ffn"], h)
+        f, _ = M.apply_moe(cfg, p["ffn"], h, layer=layer)
     else:
         f = L.apply_mlp(p["ffn"], h)
     return x + f, cl
@@ -695,8 +696,15 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
     """One decode step. token: (B,) int32; pos: scalar int32 (cache length so far).
 
     Returns (logits (B, V), new_cache); with ``moe_counts`` (MoE models)
-    also (2,) int32, summed over the MoE layers: held experts that took a
-    token, and assignments that landed on held experts.
+    also (3,) int32, summed over the MoE layers: held experts that took a
+    token, assignments that landed on held experts, and held experts whose
+    weights were read.
+
+    Where an expert layer reads only the experts its B tokens reach
+    (``moe.reads_touched``), the MoE stack's scan leaves the expert banks
+    out of what it slices and each layer reads its experts from the whole
+    ``(L, n, ...)`` banks at its index: a bank sliced per layer by the scan
+    is copied whole.
     """
     fam = cfg.family
     x = part.shard_btd(params["embed"][token][:, None, :].astype(cfg.cdtype))  # (B,1,D)
@@ -712,9 +720,25 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
             x, ncl, c = _attn_decode(cfg, layer, x, cl, pos, counts=True)
             return x, (ncl, c)
 
+        def touched(x, xs, banks):
+            layer, cl, i = xs
+            layer = {**layer, "ffn": {**layer["ffn"], **banks}}
+            x, ncl, c = _attn_decode(cfg, layer, x, cl, pos, counts=True,
+                                     layer=i)
+            return x, (ncl, c)
+
         new_cache = {}
-        for pk, ck, _ in _stacks(cfg):
-            if moe_counts and pk == "layers":
+        for pk, ck, n in _stacks(cfg):
+            if pk == "layers" and M.reads_touched(cfg, x.shape[0]):
+                ffn = params[pk]["ffn"]
+                banks = {k: ffn[k] for k in M.BANKS}
+                rest = {**params[pk], "ffn": {k: v for k, v in ffn.items()
+                                              if k not in banks}}
+                x, (new_cache[ck], c) = jax.lax.scan(
+                    functools.partial(touched, banks=banks), x,
+                    (rest, cache[ck], jnp.arange(n)))
+                counts = jnp.sum(c, axis=0)
+            elif moe_counts and pk == "layers":
                 x, (new_cache[ck], c) = jax.lax.scan(
                     counted, x, (params[pk], cache[ck]))
                 counts = jnp.sum(c, axis=0)

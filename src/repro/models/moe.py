@@ -5,7 +5,10 @@
                 partitioning; default for sharded lowering.
 ``ragged``    — sort-by-expert + ``jax.lax.ragged_dot`` grouped GEMM. No
                 capacity drops; used on CPU smoke paths and as a hillclimb
-                candidate on TPU.
+                candidate on TPU. A call with fewer assignments than held
+                experts (``T * top_k < n``, a decode step) loops over the
+                held experts its tokens reach instead, reading only their
+                weights: ``ragged_dot`` reads every group's, empty or not.
 
 Router: softmax over expert logits, top-k, renormalized combine weights, plus
 the standard load-balancing auxiliary loss (Switch Transformer eq. 4). Or, as
@@ -143,14 +146,30 @@ def moe_capacity(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
     return out, aux
 
 
-def moe_ragged(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
+BANKS = ("w_gate", "w_up", "w_down")      # the held experts' stacked weights
+
+
+def reads_touched(cfg: ModelConfig, tokens: int) -> bool:
+    """Whether a ``ragged`` expert layer on ``tokens`` tokens reads only the
+    held experts they reach: it has fewer assignments than held experts, so
+    some of them take no token (a decode step; never a prefill here)."""
+    return (cfg.n_experts > 0 and cfg.moe_impl == "ragged"
+            and tokens * cfg.top_k < cfg.n_held_experts)
+
+
+def moe_ragged(cfg: ModelConfig, p: Params, x2d: jnp.ndarray, layer=None
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sort-by-expert + ragged_dot grouped GEMM. No token drops.
 
     Only assignments to the held experts are computed: the others sort
-    last, outside every group, and add nothing. Returns (out, aux, counts):
-    counts (2,) int32 are the held experts that took at least one token and
-    the assignments that landed on held experts."""
+    last, outside every group, and add nothing. Where ``reads_touched``,
+    the held experts that took a token are applied one by one instead
+    (``_touched_experts``); only then may ``layer`` be given: p's expert
+    banks keep their leading layer axis, and this layer's are at ``layer``.
+
+    Returns (out, aux, counts): counts (3,) int32 are the held experts that
+    took at least one token, the assignments that landed on held experts,
+    and the held experts whose weights were read."""
     T, D = x2d.shape
     n, K = cfg.n_held_experts, cfg.top_k
     with jax.named_scope("moe.route"):
@@ -166,17 +185,62 @@ def moe_ragged(cfg: ModelConfig, p: Params, x2d: jnp.ndarray
                               axis=0)                            # (n,)
 
     dt = x2d.dtype
-    with jax.named_scope("moe.experts"):
-        g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), group_sizes))
-        u = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), group_sizes)
-        ys = jax.lax.ragged_dot(g * u, p["w_down"].astype(dt), group_sizes)
-        ys = jnp.where(held[order][:, None], ys * w_flat[order][:, None], 0)
-        out = jnp.zeros((T, D), dt).at[tok_flat[order]].add(ys)
+    touched = jnp.sum(group_sizes > 0)
+    if reads_touched(cfg, T):
+        out = _touched_experts(cfg, p, x2d, e_flat, w_flat, group_sizes,
+                               layer)
+        read = touched
+    else:
+        with jax.named_scope("moe.experts"):
+            g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), group_sizes))
+            u = jax.lax.ragged_dot(xs, p["w_up"].astype(dt), group_sizes)
+            ys = jax.lax.ragged_dot(g * u, p["w_down"].astype(dt), group_sizes)
+            ys = jnp.where(held[order][:, None], ys * w_flat[order][:, None], 0)
+            out = jnp.zeros((T, D), dt).at[tok_flat[order]].add(ys)
+        read = n
     if cfg.n_shared_experts:
         with jax.named_scope("moe.shared"):
             out = out + _shared_ffn(p, x2d)
-    counts = jnp.stack([jnp.sum(group_sizes > 0), jnp.sum(group_sizes)])
+    counts = jnp.stack([touched, jnp.sum(group_sizes), jnp.asarray(read)])
     return out, aux, counts.astype(jnp.int32)
+
+
+def _touched_experts(cfg: ModelConfig, p: Params, x2d, e_flat, w_flat,
+                     group_sizes, layer) -> jnp.ndarray:
+    """The held experts' part of the routed output, reading the weights of
+    the held experts that took a token (``group_sizes > 0``) and no others.
+
+    A loop over them in ascending order, as ``ragged_dot``'s groups sort;
+    each applies its SwiGLU to every token, weighted by the token's combine
+    weight for it (0 where not routed there). Each expert's matrices are
+    sliced from the banks as a whole (with ``layer``, the stacked
+    ``(L, n, ...)`` banks), so that the slice fuses into its matmul: a bank
+    sliced per layer outside the loop is copied whole."""
+    T, D = x2d.shape
+    n, K = cfg.n_held_experts, cfg.top_k
+    dt = x2d.dtype
+    # (T, n) combine weights: a token's top-k picks are distinct experts
+    gate = jnp.sum(jax.nn.one_hot(e_flat.reshape(T, K), n, dtype=dt)
+                   * w_flat.reshape(T, K, 1), axis=1)
+    ids = jnp.sort(jnp.where(group_sizes > 0, jnp.arange(n), n))  # touched first
+    at = 0 if layer is None else layer
+    banks = {k: p[k] if layer is not None else p[k][None] for k in BANKS}
+
+    def matmul(a, name, e):
+        b = banks[name]
+        w = jax.lax.dynamic_slice(b, (at, e, 0, 0), (1, 1) + b.shape[2:])
+        return jnp.dot(a, w[0, 0].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+
+    def expert(i, out):
+        e = ids[i]
+        g = jax.nn.silu(matmul(x2d, "w_gate", e))
+        y = matmul(g * matmul(x2d, "w_up", e), "w_down", e)
+        return out + y * jax.lax.dynamic_index_in_dim(gate, e, 1)
+
+    with jax.named_scope("moe.experts"):
+        return jax.lax.fori_loop(0, jnp.sum(group_sizes > 0), expert,
+                                 jnp.zeros((T, D), dt))
 
 
 def _shared_ffn(p: Params, x2d: jnp.ndarray) -> jnp.ndarray:
@@ -327,15 +391,17 @@ def moe_ep_shardmap(cfg: ModelConfig, p: Params, x: jnp.ndarray
     return out, aux
 
 
-def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray, counts: bool = False):
+def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray, counts: bool = False,
+              layer=None):
     """x: (B, S, D) -> (out, aux_loss), and with ``counts`` the ragged
-    path's (2,) routing counts (``moe_ragged``) after them."""
+    path's (3,) routing counts (``moe_ragged``) after them. ``layer``: as
+    ``moe_ragged`` takes it (stacked expert banks, the ragged path only)."""
     B, S, D = x.shape
     if cfg.moe_impl == "ragged":
-        out, aux, c = moe_ragged(cfg, p, x.reshape(B * S, D))
+        out, aux, c = moe_ragged(cfg, p, x.reshape(B * S, D), layer)
         return (out.reshape(B, S, D), aux) + ((c,) if counts else ())
     if (cfg.router_score != "softmax" or cfg.n_held_experts != cfg.n_experts
-            or counts):
+            or counts or layer is not None):
         raise ValueError(f"moe_impl {cfg.moe_impl!r} routes by softmax over "
                          f"every expert it holds; {cfg.name} needs 'ragged'")
     if cfg.moe_impl == "grouped":

@@ -71,12 +71,14 @@ def _streamable(cfg: ModelConfig) -> bool:
 
 def _moe_stats(cfg: ModelConfig, B: int, counts: np.ndarray) -> Dict[str, float]:
     """``engine.decode`` stats from a request's per-step MoE counts (steps,
-    2): held experts that took a token, per MoE layer and step, and the
-    share of routed assignments that landed on held experts."""
+    3): held experts that took a token, per MoE layer and step, the share
+    of routed assignments that landed on held experts, and held experts
+    whose weights were read, per MoE layer and step."""
     layer_steps = counts.shape[0] * cfg.n_moe_layers
     return {"experts_touched": float(counts[:, 0].sum()) / layer_steps,
             "held_share": float(counts[:, 1].sum())
-            / (layer_steps * cfg.top_k * B)}
+            / (layer_steps * cfg.top_k * B),
+            "experts_read": float(counts[:, 2].sum()) / layer_steps}
 
 
 def _rows_step(cfg: ModelConfig, params, caches, toks, positions):

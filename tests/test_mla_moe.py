@@ -3,13 +3,15 @@ reduced size of the served share (``moonlight-16b-a3b-ep4``: experts 0-1 of
 8 held, top-2, one dense layer then three MoE layers, MLA, untied head):
 prefill and decode through the latent cache, absorbed against expanded
 attention, ``noaux_tc`` routing, the expert shares against the uncut layer,
-the untied head, the engine's routing counts, and the dense models' outputs
-left as they were.
+the untied head, the engine's routing counts, the decode step's read of only
+the held experts its token reaches, and the dense models' outputs and
+Moonlight's prefill left as they were.
 
 The reference below reads the program's parameter tree and nothing of its
 code: MLA with the latent expanded, sigmoid scores with the selection bias,
 every held expert applied to every token and masked by its routing weight.
 """
+import functools
 import glob
 import hashlib
 
@@ -267,11 +269,15 @@ def _digest(*trees):
 
 @pytest.mark.parametrize("arch,want", [
     ("olmo-1b", ("603b1c81d99a612c", "fa4576d1b0d99215")),
-    ("deepseek-7b", ("5e7252a066a42653", "0f0cb862145b9562"))])
+    ("deepseek-7b", ("5e7252a066a42653", "0f0cb862145b9562")),
+    (SHARE, ("f09548b3566aa7d0", "806500ecb457ff21"))])
 def test_dense_prefill_and_decode_unchanged(arch, want):
     """The dense models' reduced prefill and decode step give, bit for bit,
-    what they gave before MLA, the mixed layout and the untied head (the
-    digests were taken from the code before them)."""
+    what they gave before MLA, the mixed layout and the untied head; the
+    reduced Moonlight share's, what they gave before decode read only the
+    touched experts (its prefill is ragged_dot's, and so is its decode: one
+    token's top-2 on 2 held experts is not fewer). The digests were taken
+    from the code before each."""
     cfg = get_config(arch).reduced()
     p = M.init_params(cfg, jax.random.PRNGKey(0))
     toks = (jnp.arange(24, dtype=jnp.int32)[None] * 37) % cfg.vocab_size
@@ -282,10 +288,12 @@ def test_dense_prefill_and_decode_unchanged(arch, want):
 
 
 def test_engine_counts_on_the_decode_span(tmp_path):
-    """Traced, ``engine.decode`` carries the held experts touched per MoE
-    layer and step and the held share of assignments, as the decode
-    steps count them; untraced it carries neither."""
-    cfg = get_config(SHARE).reduced().replace(moe_impl="ragged")
+    """Traced, ``engine.decode`` carries the held experts touched and read
+    per MoE layer and step and the held share of assignments, as the
+    decode steps count them; untraced it carries none. With 4 of 8 experts
+    held, a step's 2 assignments are fewer: it reads only those touched."""
+    cfg = get_config(SHARE).reduced().replace(held_experts=4)
+    assert MOE.reads_touched(cfg, 1)
     params = _params(cfg, seed=7)
     disk = DiskStore(str(tmp_path / "models"))
     publish_model(disk, cfg, params, name="ml")
@@ -306,12 +314,171 @@ def test_engine_counts_on_the_decode_span(tmp_path):
         params, {"tokens": jnp.asarray(prompt)})
     step = jax.jit(lambda p, c, t, pos: M.decode_step(cfg, p, c, t, pos,
                                                       moe_counts=True))
-    touched = held = 0
+    touched = held = read = 0
     for i in range(5):
         _, cache, c = step(params, cache, jnp.asarray(out[:, i]),
                            jnp.int32(16 + i))
         touched, held = touched + int(c[0]), held + int(c[1])
+        read += int(c[2])
     steps = 5 * cfg.n_moe_layers
     assert stats[0]["experts_touched"] == pytest.approx(touched / steps)
     assert stats[0]["held_share"] == pytest.approx(held / (steps * cfg.top_k))
+    assert stats[0]["experts_read"] == pytest.approx(read / steps)
+    assert read == touched
+    assert stats[0]["experts_read"] == stats[0]["experts_touched"]
     assert 0 < held < steps * cfg.top_k
+
+
+# ------------------------------------------- decode reads touched experts
+# 8 of 32 experts held, from expert 8, top-2: a call of T tokens has 2T
+# assignments, fewer than the 8 held up to T = 3
+TOUCHED = dict(n_experts=32, held_experts=8, first_held_expert=8)
+
+
+def _touched_cfg(router="sigmoid"):
+    return get_config(SHARE).reduced().replace(router_score=router, **TOUCHED)
+
+
+def _ffn(cfg, seed=0):
+    return jax.tree.map(lambda a: a[0], _params(cfg, seed)["layers"]["ffn"])
+
+
+def _grouped(monkeypatch, fn, *args):
+    """fn(*args), jitted afresh, with every expert layer on ragged_dot's
+    grouped path (a jit of fn itself would reuse fn's traced program)."""
+    with monkeypatch.context() as m:
+        m.setattr(MOE, "reads_touched", lambda cfg, tokens: False)
+        return jax.jit(lambda *a: fn(*a))(*args)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if hasattr(sub, "jaxpr") and hasattr(sub, "consts"):
+                    sub = sub.jaxpr                       # ClosedJaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _prims(jaxpr):
+    return {e.primitive.name for e in _eqns(jaxpr)}
+
+
+def _shapes(jaxpr):
+    """The shapes of every value the jaxpr and its inner jaxprs bind."""
+    out = set()
+    for e in _eqns(jaxpr):
+        for v in list(e.invars) + list(e.outvars):
+            if hasattr(v.aval, "shape"):
+                out.add(tuple(v.aval.shape))
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                for var in getattr(sub, "invars", ()):
+                    out.add(tuple(var.aval.shape))
+    return out
+
+
+def _force(cfg, p, x, experts):
+    """p with routing that sends token 0 of x to ``experts``: the selection
+    bias (sigmoid), or router columns along x[0] (softmax)."""
+    p = dict(p)
+    if cfg.router_score == "sigmoid":
+        p["router_bias"] = jnp.zeros_like(p["router_bias"]).at[
+            jnp.asarray(experts)].set(10.0)
+    else:
+        along = x[0].astype(jnp.float32) / jnp.sum(x[0].astype(jnp.float32) ** 2)
+        p["router"] = p["router"].at[:, jnp.asarray(experts)].add(
+            8.0 * along[:, None])
+    return p
+
+
+@pytest.mark.parametrize("router", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("case", ["T1", "T2", "none_held", "all_held"])
+def test_touched_experts_match_the_grouped_path(monkeypatch, router, case):
+    """Where a call has fewer assignments than held experts, reading only
+    the touched experts gives ragged_dot's grouped output within one
+    bfloat16 rounding step, and the same counts: touched, assignments
+    held, and now read (the touched, not all 8)."""
+    cfg = _touched_cfg(router)
+    T = 2 if case == "T2" else 1
+    p = _ffn(cfg, seed=11)
+    x = jax.random.normal(jax.random.PRNGKey(12), (T, cfg.d_model)
+                          ).astype(cfg.cdtype)
+    # held: 8..15; T1 and T2 send token 0 (sigmoid: each token) to expert 9
+    p = _force(cfg, p, x, {"none_held": [0, 20], "all_held": [9, 14]}
+               .get(case, [9]))
+    assert MOE.reads_touched(cfg, T)
+    layer = functools.partial(MOE.moe_ragged, cfg)
+    got, _, c = jax.jit(layer)(p, x)
+    want, _, cw = _grouped(monkeypatch, layer, p, x)
+    c, cw = np.asarray(c).tolist(), np.asarray(cw).tolist()
+    assert c == [cw[0], cw[1], cw[0]] and cw[2] == cfg.n_held_experts
+    held = {"none_held": 0, "all_held": 2}.get(case)
+    if held is not None:
+        assert c[:2] == [held, held]
+    else:
+        assert c[0] >= 1 and c[1] >= 1
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if case == "none_held":
+        np.testing.assert_array_equal(got, want)          # shared part alone
+    # each path rounds its own bfloat16 intermediates: one step of each
+    step = np.abs(want).max() * 2.0 ** -7
+    np.testing.assert_allclose(got, want, rtol=0, atol=2 * step)
+    if router == "sigmoid":         # and the touched path is no less exact
+        f32 = jax.tree.map(lambda a: a.astype(jnp.float32), p)
+        ref = np.asarray(ref_moe(cfg, f32, x.astype(jnp.float32),
+                                 cfg.first_held_expert, cfg.n_held_experts))
+        assert np.abs(got - ref).max() <= np.abs(want - ref).max() + step / 2
+
+
+def test_many_assignments_take_ragged_dot():
+    """The rule is the call's shape: 3 tokens x top-2 < 8 held loops over
+    the touched experts; 4 x 2 = 8 held goes to ragged_dot and reads all."""
+    cfg = _touched_cfg()
+    p = _ffn(cfg)
+    for T, grouped in ((3, False), (4, True)):
+        x = jax.random.normal(jax.random.PRNGKey(T), (T, cfg.d_model)
+                              ).astype(cfg.cdtype)
+        assert MOE.reads_touched(cfg, T) is not grouped
+        jx = jax.make_jaxpr(lambda p, x: MOE.moe_ragged(cfg, p, x))(p, x)
+        assert ("ragged_dot_general" in _prims(jx.jaxpr)) is grouped
+        touched, _, read = np.asarray(MOE.moe_ragged(cfg, p, x)[2]).tolist()
+        assert read == (cfg.n_held_experts if grouped else touched)
+
+
+def test_moe_decode_reads_no_whole_bank(monkeypatch):
+    """The MoE decode step holds no ragged_dot and no value of one layer's
+    whole expert bank (n, D, F): each touched expert's matrices are sliced
+    from the stacked banks. Prefill keeps ragged_dot. The step's logits
+    are the grouped path's within bfloat16 rounding, its counts equal."""
+    cfg = _touched_cfg()
+    params = _params(cfg, seed=3)
+    toks = jnp.asarray(TOKENS[None, :16])
+    pre = jax.jit(lambda p, b: M.prefill(cfg, p, b, 24))
+    lg, cache = pre(params, {"tokens": toks})
+    tok = jnp.argmax(lg, -1).astype(jnp.int32)
+
+    def step(p, c, t):
+        return M.decode_step(cfg, p, c, t, jnp.int32(16), moe_counts=True)
+
+    jx = jax.make_jaxpr(step)(params, cache, tok).jaxpr
+    n, d, f = cfg.n_held_experts, cfg.d_model, cfg.d_ff
+    assert "ragged_dot_general" not in _prims(jx)
+    assert not {(n, d, f), (n, f, d)} & _shapes(jx)
+    assert {(1, 1, d, f), (1, 1, f, d)} <= _shapes(jx)
+    assert "ragged_dot_general" in _prims(
+        jax.make_jaxpr(lambda p, b: M.prefill(cfg, p, b, 24))(
+            params, {"tokens": toks}).jaxpr)
+
+    got, _, c = jax.jit(step)(params, cache, tok)
+    want, _, cw = _grouped(monkeypatch, step, params, cache, tok)
+    assert np.asarray(c).tolist() == [int(cw[0]), int(cw[1]), int(cw[0])]
+    assert int(cw[2]) == n * cfg.n_moe_layers
+    got = np.asarray(got[0, :cfg.vocab_size], np.float32)
+    want = np.asarray(want[0, :cfg.vocab_size], np.float32)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2 * np.abs(want).max() * 2.0 ** -7)

@@ -95,3 +95,33 @@ def test_olmo_1b_decode_step_compiles_for_v5e(one_chip):
                        ).lower(params, cache, token, pos).compile()
     mem = compiled.memory_analysis()
     assert 2 << 30 < mem.argument_size_in_bytes < 16 << 30
+
+
+def test_moonlight_decode_step_reads_no_whole_bank_on_v5e(one_chip):
+    """The Moonlight share's B=1 decode step at its published widths fits
+    one v5e, and no value in it is one layer's whole (16, 2048, 1408) bank
+    of held experts: each touched expert's matrices are sliced from the
+    stacked banks inside its matmul (a bank the layer scan slices is
+    copied whole, and read whole by ragged_dot)."""
+    import re
+
+    cfg = get_config("moonlight-16b-a3b-ep4")
+    B, max_len = 1, 40
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree)
+
+    params = on_chip(jax.eval_shape(lambda k: M.init_params(cfg, k),
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: M.init_cache(cfg, B, max_len)))
+    token = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, c, t, i: M.decode_step(cfg, p, c, t, i,
+                                                        moe_counts=True)
+                       ).lower(params, cache, token, pos).compile()
+    n, d, f = cfg.n_held_experts, cfg.d_model, cfg.d_ff
+    assert params["layers"]["ffn"]["w_gate"].shape == (26, n, d, f)
+    assert not re.search(rf"bf16\[{n},({d},{f}|{f},{d})\]", compiled.as_text())
+    assert compiled.memory_analysis().argument_size_in_bytes < 16 << 30
