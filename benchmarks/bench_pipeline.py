@@ -1,56 +1,13 @@
-"""Staging-pipeline and eviction-demotion ablations (DESIGN.md §2/§4).
+"""Eviction-demotion ablation (DESIGN.md §2).
 
-Two mechanisms the async tier-hierarchy refactor added, each measured with
-its ablation switch:
-
-  * ``--ablate-pipeline`` (default on): cold-open the same models through an
-    MRM with chunked pipelined staging vs whole-model serial staging. Both
-    real wall time on this host and the modeled TPU staging times are
-    reported; the modeled pipelined time must be strictly below serial.
-  * ``--ablate-demotion`` (default on): a device tier that fits one model
-    alternating between two models. With eviction-as-demotion the loser of
-    each eviction lands in the host tier, so reloads are host hits; with
-    drop-on-evict every reload goes back to disk.
+A device tier that fits two models rotating through three. With
+eviction-as-demotion the loser of each eviction lands in the host tier, so
+reloads are host hits; with drop-on-evict every reload goes back to disk.
 """
 from __future__ import annotations
 
-import time
-
-import numpy as np
-
 from benchmarks.common import BenchEnv, write_csv
-from repro.core import MRM, ModelKey, Tier
-
-PIPE_MODELS = ["VGG16", "ResNet152", "WRN50-v2", "Inception-v3"]
-
-
-def run_pipeline_ablation(env: BenchEnv, verbose=True):
-    rows = []
-    for pipelined in (False, True):
-        for name in PIPE_MODELS:
-            mrm = env.make_mrm(pipelined_staging=pipelined,
-                               staging_chunk_bytes=256 << 10)
-            key = ModelKey("repro-jax", name, "1")
-            t0 = time.perf_counter()
-            h = mrm.open(key)
-            wall = time.perf_counter() - t0
-            t = h.timings
-            rows.append({
-                "model": name, "pipelined": pipelined, "wall_s": wall,
-                "chunks": t.chunks, "stage_overlap_s": t.stage_overlap_s,
-                "disk_read_s": t.disk_read_s,
-                "deserialize_s": t.deserialize_s,
-                "h2d_measured_s": t.h2d_measured_s,
-                "staging_serial_modeled_s": t.staging_serial_modeled_s,
-                "staging_pipelined_modeled_s": t.staging_pipelined_modeled_s,
-            })
-            mrm.close(h)
-            if verbose:
-                print(f"  pipelined={pipelined!s:<5} {name:<14} "
-                      f"wall={wall*1e3:7.1f}ms chunks={t.chunks:3d} "
-                      f"overlap={t.stage_overlap_s*1e3:6.1f}ms")
-    write_csv("pipeline_ablation", rows)
-    return rows
+from repro.core import MRM, ModelKey
 
 
 def run_demotion_ablation(env: BenchEnv, n_rounds: int = 4, verbose=True,
@@ -97,36 +54,18 @@ def run_demotion_ablation(env: BenchEnv, n_rounds: int = 4, verbose=True,
     return rows
 
 
-def run(env: BenchEnv | None = None, pipeline=True, demotion=True, verbose=True):
+def run(env: BenchEnv | None = None, verbose=True):
     env = env or BenchEnv()
-    out = {}
-    if pipeline:
-        if verbose:
-            print("-- chunked pipelined staging vs serial --")
-        out["pipeline"] = run_pipeline_ablation(env, verbose)
-        mod = [(r["staging_pipelined_modeled_s"], r["staging_serial_modeled_s"])
-               for r in out["pipeline"] if r["pipelined"]]
-        assert all(p < s for p, s in mod), "pipelined model must beat serial"
-    if demotion:
-        if verbose:
-            print("-- eviction-as-demotion vs drop --")
-        out["demotion"] = run_demotion_ablation(env, verbose=verbose)
-        with_d = next(r for r in out["demotion"] if r["demote_on_evict"])
-        without = next(r for r in out["demotion"] if not r["demote_on_evict"])
-        if verbose:
-            saved = without["disk_loads"] - with_d["disk_loads"]
-            print(f"  demotion saved {saved} disk reloads")
-    return out
+    if verbose:
+        print("-- eviction-as-demotion vs drop --")
+    rows = run_demotion_ablation(env, verbose=verbose)
+    with_d = next(r for r in rows if r["demote_on_evict"])
+    without = next(r for r in rows if not r["demote_on_evict"])
+    if verbose:
+        saved = without["disk_loads"] - with_d["disk_loads"]
+        print(f"  demotion saved {saved} disk reloads")
+    return {"demotion": rows}
 
 
 if __name__ == "__main__":
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--ablate-pipeline", dest="pipeline", action="store_true",
-                    default=True)
-    ap.add_argument("--no-pipeline", dest="pipeline", action="store_false")
-    ap.add_argument("--ablate-demotion", dest="demotion", action="store_true",
-                    default=True)
-    ap.add_argument("--no-demotion", dest="demotion", action="store_false")
-    args = ap.parse_args()
-    run(pipeline=args.pipeline, demotion=args.demotion)
+    run()

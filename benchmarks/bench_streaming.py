@@ -125,15 +125,14 @@ def run_mechanism(root: str, verbose: bool = True) -> list:
         params = init_params(cfg, jax.random.PRNGKey(0))
         d_ref = DiskStore(os.path.join(root, arch, "ref"))
         key = publish_model(d_ref, cfg, params, name=arch)
-        eng_ref = InferenceEngine(d_ref, MRM(d_ref, pipelined_staging=False))
+        eng_ref = InferenceEngine(d_ref, MRM(d_ref))
 
         store = ObjectStore(os.path.join(root, arch, "obj"))
         store.put_file(key, d_ref.path_for(key), shard_plan="layers",
                        shard_bytes=64 * 1024)
         d_cold = DiskStore(os.path.join(root, arch, "cold"))
-        eng_s = InferenceEngine(
-            d_cold, MRM(d_cold, objectstore=store, pipelined_staging=False),
-            streaming=True)
+        eng_s = InferenceEngine(d_cold, MRM(d_cold, objectstore=store),
+                                streaming=True)
 
         toks = (np.arange(8, dtype=np.int32).reshape(1, 8)) % cfg.vocab_size
         out_ref, st_ref = eng_ref.generate(arch, toks, max_new_tokens=4)

@@ -94,7 +94,6 @@ class TrimsClient:
 
 
 def cold_load(disk: DiskStore, key: ModelKey, device_put_fn=None,
-              simulate_h2d_time: bool = False,
               objectstore=None) -> LoadedModel:
     """Baseline path: what an unmodified framework does on every cold start —
     read from disk, deserialize, copy to device. No sharing, no persistence.
@@ -123,8 +122,6 @@ def cold_load(disk: DiskStore, key: ModelKey, device_put_fn=None,
     weights = {n: device_put_fn(a) for n, a in arrays.items()}
     timings.h2d_measured_s = time.perf_counter() - t0
     timings.h2d_modeled_s = hw.h2d_time(nbytes)
-    if simulate_h2d_time and timings.h2d_measured_s < timings.h2d_modeled_s:
-        time.sleep(min(timings.h2d_modeled_s - timings.h2d_measured_s, 0.25))
     timings.total_s = time.perf_counter() - t_start
     return LoadedModel(key, weights, nbytes, timings, via_trims=False)
 

@@ -6,8 +6,8 @@ Fig. 7 state machine as a :class:`LoadFuture`:
 
   DEVICE hit             -> refcount++, hand out shared device arrays
   DEVICE miss / HOST hit -> make room on device, stage host->device
-  HOST+DEVICE miss       -> disk, then a *chunked pipelined*
-                            disk->host->device staging chain
+  HOST+DEVICE miss       -> disk into HOST by a *chunked pipelined*
+                            read | deserialize, then the stage above
   DISK miss              -> fetch from a peer node or the CLOUD tier
                             (whichever the cost model says is cheaper),
                             then the cold chain above (DESIGN.md §6)
@@ -350,9 +350,7 @@ class MRM:
                  eager_reclaim: bool = False,
                  use_shm: bool = False,
                  device_put_fn: Callable = _default_device_put,
-                 simulate_h2d_time: bool = False,
                  demote_on_evict: bool = True,
-                 pipelined_staging: bool = True,
                  staging_chunk_bytes: int = PIPELINE_CHUNK_BYTES,
                  pipeline_depth: int = 2,
                  objectstore=None,
@@ -396,8 +394,6 @@ class MRM:
         self.eager_reclaim = eager_reclaim
         self.use_shm = use_shm
         self.device_put_fn = device_put_fn
-        self.simulate_h2d_time = simulate_h2d_time
-        self.pipelined_staging = pipelined_staging
         self.staging_chunk_bytes = staging_chunk_bytes
         self.pipeline_depth = pipeline_depth
         self._handles: Dict[int, ModelHandle] = {}
@@ -408,7 +404,7 @@ class MRM:
             "opens": 0, "closes": 0, "coalesced_loads": 0,
             "cloud_downloads": 0, "disk_loads": 0, "h2d_stages": 0,
             "bytes_from_disk": 0, "bytes_h2d": 0,
-            "prefetches": 0, "pipelined_loads": 0,
+            "prefetches": 0,
             "peer_fetches": 0, "gather_fetches": 0, "cloud_writebacks": 0,
             "cloud_writeback_errors": 0,
             # streaming (partial) opens — DESIGN.md §9
@@ -982,8 +978,6 @@ class MRM:
             timings.tier_hit = "disk"
             if fut.streaming:
                 return self._load_host_streaming(fut)
-            if fut.tier == "device" and self.pipelined_staging:
-                return self._load_cold_pipelined(fut)
             host_entry = self._load_host(key, timings, fut)  # still pinned
         else:
             timings.tier_hit = "host"
@@ -1122,14 +1116,12 @@ class MRM:
             self._stage_queue.put(None)
             waiter.join(timeout)
 
-    def _stage_span(self, key, nbytes: int, source: str,
-                    fut: Optional[LoadFuture]):
-        """Enter the ``mrm.stage`` span of one staging; None unless a
-        profiler records."""
+    def _stage_span(self, key, nbytes: int, fut: Optional[LoadFuture]):
+        """Enter the ``mrm.stage`` span of one host -> device staging; None
+        unless a profiler records."""
         if not spans.tracing():
             return None
         sp = spans.span("mrm.stage", model=key.name, bytes=nbytes,
-                        source=source,
                         prefetch=int(fut is not None and not fut.want_handle))
         sp.__enter__()
         return sp
@@ -1202,167 +1194,42 @@ class MRM:
                     raw, dtype=_np_dtype(tm.dtype)).reshape(tm.shape)
         return arrays, segs, write
 
-    def _disk_stages(self, mf: ModelFile, f, write,
-                     fut: Optional[LoadFuture] = None):
-        """The shared disk_read/deserialize pipeline stages: chunked reads
-        through the open handle ``f``, deserialized via the sink's ``write``."""
-
-        def read_chunk(names):
-            if fut is not None:
-                fut.stage = "disk_read"
-            out = []
-            for n in names:
-                t = mf.tensors[n]
-                f.seek(mf.payload_base + t.offset)
-                out.append((n, f.read(t.nbytes)))
-            return out
-
-        def deser_chunk(items):
-            if fut is not None:
-                fut.stage = "deserialize"
-            for n, raw in items:
-                write(n, raw)
-            return [n for n, _ in items]
-
-        return ("disk_read", read_chunk), ("deserialize", deser_chunk)
-
     def _record_staging_models(self, timings, nbytes: int):
         timings.h2d_modeled_s = self.hw.h2d_time(nbytes)
         timings.staging_serial_modeled_s = self.hw.staging_serial_time(nbytes)
         timings.staging_pipelined_modeled_s = self.hw.staging_pipelined_time(
             nbytes, self.staging_chunk_bytes)
 
-    def _maybe_simulate_h2d(self, timings):
-        if self.simulate_h2d_time and timings.h2d_measured_s < timings.h2d_modeled_s:
-            time.sleep(min(timings.h2d_modeled_s - timings.h2d_measured_s, 0.25))
-
-    def _load_cold_pipelined(self, fut: LoadFuture) -> Optional[ModelHandle]:
-        """HOST+DEVICE miss, device wanted: one three-stage chunk pipeline
-        (disk read | deserialize | H2D) filling BOTH tiers as chunks flow —
-        I/O overlaps deserialization overlaps device staging (DESIGN.md §4).
-        """
-        key, timings = fut.key, fut.timings
-        self._ensure_on_disk(key, timings, ctx=fut.ctx)
-        with self._evict_lock:
-            self._demoted_keys.discard(key)  # any demoted copy lapsed
-        mf = self.disk.open(key)
-        nbytes = mf.total_bytes
-
-        # reserve both tiers up front (device first: lock order DEVICE->HOST;
-        # placeholders are pinned so another model's eviction pass cannot
-        # reap a half-staged entry). Victims demote AFTER the device lock
-        # drops — the D2H copy must not stall concurrent opens.
-        with self.device.lock:
-            evicted = self.tiers.make_room(Tier.DEVICE,
-                                           nbytes + fut.activation_bytes)
-            d_entry = self.device.insert(key, nbytes, payload=None)
-            d_entry.pinned = True
-        h_entry = None
-        adopted = None
-        sp = None
-        segs = []
-        try:
-            # reserve HOST room for the incoming model BEFORE demoting the
-            # device victims into it — demoting first would pay the D2H copy
-            # for entries this very reservation may immediately evict
-            with self.host.lock:
-                existing = self.host.peek(key)
-                if existing is not None and existing.payload is not None:
-                    # a concurrent demotion (of OUR key, evicted by some
-                    # other model's load) re-homed it in HOST between the
-                    # host-miss check and this reservation. Models are
-                    # immutable, so the copy is interchangeable: take a
-                    # provisional ref and stage the device tier from it
-                    # instead of colliding on the insert
-                    existing.refcount += 1
-                    adopted = existing
-                else:
-                    self.tiers.make_room(Tier.HOST, nbytes)
-                    h_entry = self.host.insert(key, nbytes, payload=None)
-                    h_entry.pinned = True
-            if adopted is None:
-                # from the demotion of the device victims to the last copy
-                sp = self._stage_span(key, nbytes, "disk", fut)
-            demoted = self.tiers.demote_evicted(evicted)
-            timings.demote_s = sum(self.hw.d2h_time(v.nbytes) for v in demoted)
-            if demoted:
-                with self._evict_lock:
-                    self._demoted_keys.update(v.key for v in demoted)
-            if adopted is not None:
-                # hand our device reservation back (stage_device re-reserves
-                # atomically) and run the warm HOST -> DEVICE chain
-                with self.device.lock:
-                    if self.device.peek(key) is d_entry:
-                        self.device.remove(key)
-                timings.tier_hit = "host"
-                try:
-                    dev_entry = self._stage_device(
-                        key, adopted, fut.activation_bytes, timings, fut)
-                finally:
-                    with self.host.lock:
-                        adopted.refcount -= 1
-                return self._finish_entry(fut, self.device, dev_entry,
-                                          unpin=True)
-
-            arrays, segs, write = self._host_sink(mf, key, nbytes)
-            weights: Dict[str, object] = {}
-            chunks = plan_chunks(
-                [(t.name, t.nbytes) for t in mf.tensors.values()],
-                self.staging_chunk_bytes)
-
-            def put_chunk(names):
-                fut.stage = "h2d"
-                for n in names:
-                    weights[n] = self.device_put_fn(arrays[n])
-                return names
-
-            with open(mf.path, "rb") as f:
-                _, report = run_pipeline(
-                    chunks,
-                    [*self._disk_stages(mf, f, write, fut),
-                     ("h2d", put_chunk)],
-                    depth=self.pipeline_depth)
-        except BaseException:
-            if sp is not None:
-                sp.__exit__(None, None, None)
-            # roll back both reservations or the pinned placeholders brick
-            # the key (payload-None entries are treated as misses, but the
-            # next loader's insert would collide)
-            with self.device.lock:
-                if self.device.peek(key) is d_entry:
-                    self.device.remove(key)
-            if h_entry is not None:
-                with self.host.lock:
-                    if self.host.peek(key) is h_entry:
-                        self.host.remove(key)
-            for seg in segs:
-                seg.close_and_unlink()
-            raise
-        self._stage_done(sp, weights)
-
-        timings.disk_read_s = report.stage("disk_read").busy_s
-        timings.deserialize_s = report.stage("deserialize").busy_s
-        timings.h2d_measured_s = report.stage("h2d").busy_s
-        timings.chunks = max(timings.chunks, report.n_chunks)
-        timings.stage_overlap_s += report.overlap_s()  # adds to fetch overlap
-        self._record_staging_models(timings, nbytes)
-        self._maybe_simulate_h2d(timings)
-
-        h_entry.payload = HostModel(arrays, nbytes, segs)
-        d_entry.payload = weights
+    def _reserve_host(self, key, nbytes: int):
+        """Reserve a pinned HOST placeholder for ``key``, or adopt the copy
+        a concurrent demotion re-homed there since the host-miss check
+        (models are immutable, so it is interchangeable), pinned exactly as
+        a fresh load would be. Check and insert share one cache lock, so an
+        eviction pass can neither reap the in-flight entry nor double-home
+        the key. Returns ``(entry, adopted)``."""
         with self.host.lock:
-            h_entry.pinned = False
-        with self._lock:
-            self.metrics["disk_loads"] += 1
-            self.metrics["bytes_from_disk"] += nbytes
-            self.metrics["h2d_stages"] += 1
-            self.metrics["bytes_h2d"] += nbytes
-            self.metrics["pipelined_loads"] += 1
-            self.metrics["modeled_stage_s"] += timings.staging_pipelined_modeled_s
-        return self._finish_entry(fut, self.device, d_entry, unpin=True)
+            entry = self.host.peek(key)
+            if entry is not None and entry.payload is not None:
+                entry.pinned = True
+                return entry, True
+            self.tiers.make_room(Tier.HOST, nbytes)
+            entry = self.host.insert(key, nbytes, payload=None)
+            entry.pinned = True
+            return entry, False
+
+    @staticmethod
+    def _drop_placeholder(cache: TierCache, key, entry) -> None:
+        """Roll back a reservation that is still ours: a pinned payload-None
+        placeholder reads as a miss, but would make the next loader's
+        insert collide and brick the key."""
+        if entry is None:
+            return
+        with cache.lock:
+            if cache.peek(key) is entry:
+                cache.remove(key)
 
     def _load_host(self, key, timings, fut: Optional[LoadFuture] = None):
-        """Disk/cloud -> host tier only (host-tier opens, or serial mode).
+        """Disk/cloud -> HOST tier: chunked disk read | deserialize pipeline.
 
         Returns the entry STILL PINNED; the caller releases the pin once
         the handle refcount (or device staging) no longer needs it."""
@@ -1372,60 +1239,51 @@ class MRM:
             self._demoted_keys.discard(key)  # any demoted copy lapsed
         mf = self.disk.open(key)
         nbytes = mf.total_bytes
-
-        with self.host.lock:
-            entry = self.host.peek(key)
-            if entry is not None and entry.payload is not None:
-                # a concurrent demotion re-homed this key between the
-                # host-miss check and this reservation; the copy is
-                # interchangeable (models are immutable) — adopt it,
-                # pinned exactly as a fresh load would be
-                entry.pinned = True
-                timings.tier_hit = "host"
-                return entry
-            self.tiers.make_room(Tier.HOST, nbytes)
-            entry = self.host.insert(key, nbytes, payload=None)
-            entry.pinned = True
+        entry, adopted = self._reserve_host(key, nbytes)
+        if adopted:
+            timings.tier_hit = "host"
+            return entry
 
         segs = []
         try:
             arrays, segs, write = self._host_sink(mf, key, nbytes)
-            if self.pipelined_staging:
-                chunks = plan_chunks(
-                    [(t.name, t.nbytes) for t in mf.tensors.values()],
-                    self.staging_chunk_bytes)
-                with open(mf.path, "rb") as f:
-                    _, report = run_pipeline(
-                        chunks, list(self._disk_stages(mf, f, write, fut)),
-                        depth=self.pipeline_depth)
-                timings.disk_read_s = report.stage("disk_read").busy_s
-                timings.deserialize_s = report.stage("deserialize").busy_s
-                timings.chunks = max(timings.chunks, report.n_chunks)
-                timings.stage_overlap_s += report.overlap_s()
-                hm = HostModel(arrays, nbytes, segs)
-                with self._lock:
-                    self.metrics["pipelined_loads"] += 1
-            else:
-                t0 = time.perf_counter()
-                with open(mf.path, "rb") as f:
-                    for name, tm in mf.tensors.items():
-                        f.seek(mf.payload_base + tm.offset)
-                        write(name, f.read(tm.nbytes))
-                hm = HostModel(arrays, nbytes, segs)
-                dt = time.perf_counter() - t0
-                # attribute: raw I/O at measured disk bw, remainder = deserialize
-                io_est = self.hw.disk_time(nbytes)
-                timings.disk_read_s = min(dt, io_est)
-                timings.deserialize_s = max(0.0, dt - timings.disk_read_s)
+            chunks = plan_chunks(
+                [(t.name, t.nbytes) for t in mf.tensors.values()],
+                self.staging_chunk_bytes)
+            with open(mf.path, "rb") as f:
+
+                def read_chunk(names):
+                    if fut is not None:
+                        fut.stage = "disk_read"
+                    out = []
+                    for n in names:
+                        t = mf.tensors[n]
+                        f.seek(mf.payload_base + t.offset)
+                        out.append((n, f.read(t.nbytes)))
+                    return out
+
+                def deser_chunk(items):
+                    if fut is not None:
+                        fut.stage = "deserialize"
+                    for n, raw in items:
+                        write(n, raw)
+                    return [n for n, _ in items]
+
+                _, report = run_pipeline(
+                    chunks, [("disk_read", read_chunk),
+                             ("deserialize", deser_chunk)],
+                    depth=self.pipeline_depth)
         except BaseException:
-            with self.host.lock:
-                if self.host.peek(key) is entry:
-                    self.host.remove(key)
+            self._drop_placeholder(self.host, key, entry)
             for seg in segs:
                 seg.close_and_unlink()
             raise
+        timings.disk_read_s = report.stage("disk_read").busy_s
+        timings.deserialize_s = report.stage("deserialize").busy_s
+        timings.chunks = max(timings.chunks, report.n_chunks)
+        timings.stage_overlap_s += report.overlap_s()
 
-        entry.payload = hm
+        entry.payload = HostModel(arrays, nbytes, segs)
         with self._lock:
             self.metrics["disk_loads"] += 1
             self.metrics["bytes_from_disk"] += nbytes
@@ -1470,20 +1328,8 @@ class MRM:
         state = {"entry": None, "adopted": None}
 
         def reserve(nb):
-            # mirrors _load_host's reservation: adoption check + pinned
-            # placeholder under one cache lock, so concurrent eviction
-            # passes can neither reap the in-flight entry nor double-home
-            # the key
-            with self.host.lock:
-                e = self.host.peek(key)
-                if e is not None and e.payload is not None:
-                    e.pinned = True
-                    state["adopted"] = e
-                    return
-                self.tiers.make_room(Tier.HOST, nb)
-                entry = self.host.insert(key, nb, payload=None)
-                entry.pinned = True
-                state["entry"] = entry
+            entry, adopted = self._reserve_host(key, nb)
+            state["adopted" if adopted else "entry"] = entry
 
         if not private and est:
             reserve(est)
@@ -1521,10 +1367,7 @@ class MRM:
                 raise IOError(f"streaming load of {key} left windows "
                               f"{missing} incomplete")
         except BaseException:
-            with self.host.lock:
-                entry = state["entry"]
-                if entry is not None and self.host.peek(key) is entry:
-                    self.host.remove(key)
+            self._drop_placeholder(self.host, key, state["entry"])
             raise
         timings.deserialize_s += asm.scatter_s
         nbytes = sum(int(a.nbytes) for a in asm.arrays.values())
@@ -1607,10 +1450,10 @@ class MRM:
 
     def _stage_device(self, key, host_entry, activation_bytes, timings,
                       fut: Optional[LoadFuture] = None):
-        """HOST hit -> device: chunked H2D (double-buffered when pipelined)."""
+        """HOST copy -> device: reserve room, demote the victims, then put
+        each tensor on the device in order from the calling thread."""
         nbytes = host_entry.nbytes
-        need = nbytes + activation_bytes
-        sp = self._stage_span(key, nbytes, "host", fut)
+        sp = self._stage_span(key, nbytes, fut)
         hm: HostModel = host_entry.payload
         weights: Dict[str, object] = {}
         entry = None
@@ -1621,7 +1464,8 @@ class MRM:
             # victims demote to HOST after the lock drops (D2H copy must
             # not stall other opens)
             with self.device.lock:
-                evicted = self.tiers.make_room(Tier.DEVICE, need)
+                evicted = self.tiers.make_room(Tier.DEVICE,
+                                               nbytes + activation_bytes)
                 entry = self.device.insert(key, nbytes, payload=None)
                 entry.pinned = True
             demoted = self.tiers.demote_evicted(evicted)
@@ -1629,42 +1473,19 @@ class MRM:
             if demoted:
                 with self._evict_lock:
                     self._demoted_keys.update(v.key for v in demoted)
-            if self.pipelined_staging:
-                chunks = plan_chunks([(n, a.nbytes) for n, a in hm.arrays.items()],
-                                     self.staging_chunk_bytes)
-
-                def prep_chunk(names):
-                    return [(n, hm.arrays[n]) for n in names]
-
-                def put_chunk(items):
-                    if fut is not None:
-                        fut.stage = "h2d"
-                    for n, a in items:
-                        weights[n] = self.device_put_fn(a)
-                    return [n for n, _ in items]
-
-                _, report = run_pipeline(chunks, [("host_prep", prep_chunk),
-                                                  ("h2d", put_chunk)],
-                                         depth=self.pipeline_depth)
-                timings.h2d_measured_s = report.stage("h2d").busy_s
-                timings.chunks = max(timings.chunks, report.n_chunks)
-                timings.stage_overlap_s += report.overlap_s()
-            else:
-                t0 = time.perf_counter()
-                for n, a in hm.arrays.items():
-                    weights[n] = self.device_put_fn(a)
-                timings.h2d_measured_s = time.perf_counter() - t0
+            if fut is not None:
+                fut.stage = "h2d"
+            t0 = time.perf_counter()
+            for n, a in hm.arrays.items():
+                weights[n] = self.device_put_fn(a)
+            timings.h2d_measured_s = time.perf_counter() - t0
         except BaseException:
             if sp is not None:
                 sp.__exit__(None, None, None)
-            if entry is not None:
-                with self.device.lock:
-                    if self.device.peek(key) is entry:
-                        self.device.remove(key)
+            self._drop_placeholder(self.device, key, entry)
             raise
         self._stage_done(sp, weights)
         self._record_staging_models(timings, nbytes)
-        self._maybe_simulate_h2d(timings)
         with self._lock:
             self.metrics["h2d_stages"] += 1
             self.metrics["bytes_h2d"] += nbytes

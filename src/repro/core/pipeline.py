@@ -10,8 +10,8 @@ stage runs in its own thread, connected by bounded queues of depth
 multi-tier staging needs to hide I/O behind PCIe transfers.
 
 The runner is deliberately generic (items in, per-stage callables, stats
-out) so the MRM uses one mechanism for disk->host, host->device, and the
-full three-stage cold path — the compressed-transfer paths
+out): the MRM's disk->host load runs ``disk_read | deserialize`` on it, and
+the compressed-transfer paths
 (ObjectStore fetch, peer wire) use the same runner with a **decompress**
 stage in the chain, so decode overlaps the transfer instead of
 serializing after it (DESIGN.md §4), and the cluster's multi-source
@@ -25,7 +25,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 _STOP = object()
 
@@ -36,6 +36,11 @@ class StageStats:
     busy_s: float = 0.0
     items: int = 0
     bytes: int = 0  # only counted for stages declared with a sizer
+    # perf_counter stamps of the first item's start and the last one's end:
+    # two stages ran concurrently when one's first start precedes the
+    # other's last end, however loaded the host
+    first_start_s: Optional[float] = None
+    last_end_s: Optional[float] = None
 
 
 @dataclass
@@ -122,8 +127,12 @@ def run_pipeline(items: Sequence[object],
             except BaseException as e:  # noqa: BLE001 — re-raised by caller
                 errors.append(e)
                 continue
+            t1 = time.perf_counter()
             st = report.stages[idx]
-            st.busy_s += time.perf_counter() - t0
+            st.busy_s += t1 - t0
+            if st.first_start_s is None:
+                st.first_start_s = t0
+            st.last_end_s = t1
             st.items += 1
             if sizer is not None:
                 st.bytes += sizer(res)

@@ -54,7 +54,8 @@ class ShmSegment:
 
     @classmethod
     def create(cls, key, nbytes: int) -> "ShmSegment":
-        name = f"trims_{uuid.uuid4().hex[:16]}"
+        # the creator's pid in the name tells whose segment a leftover is
+        name = f"trims_{os.getpid()}_{uuid.uuid4().hex[:16]}"
         return cls(shared_memory.SharedMemory(create=True, size=max(1, nbytes),
                                               name=name), owner=True)
 

@@ -32,13 +32,20 @@ def register_daemon():
     yield _register
 
 
+def _segments(pids):
+    """The ``trims_<pid>_*`` shm segments the processes ``pids`` created."""
+    return {path for pid in pids
+            for path in glob.glob(f"/dev/shm/trims_{pid}_*")}
+
+
 @pytest.fixture(autouse=True)
 def _reap_daemons_and_shm(request):
     """Kill leftover daemons and unlink orphaned trims_* shm segments.
 
-    Only segments created DURING the test are reaped — a parallel run's
-    segments (different test process, same /dev/shm) are left alone."""
-    before = set(glob.glob("/dev/shm/trims_*"))
+    Only segments created DURING the test, by this test process or a
+    daemon it registered, are reaped: another worker's segments (a
+    different process, same /dev/shm) are left alone."""
+    before = _segments([os.getpid()])
     reg = (request.getfixturevalue("register_daemon")
            if "register_daemon" in request.fixturenames else None)
     yield
@@ -52,7 +59,8 @@ def _reap_daemons_and_shm(request):
             except Exception:  # noqa: BLE001 — SIGTERM ignored: force it
                 p.kill()
                 p.wait(timeout=10)
-    for path in set(glob.glob("/dev/shm/trims_*")) - before:
+    pids = [os.getpid()] + ([p.pid for p in reg.procs] if reg else [])
+    for path in _segments(pids) - before:
         try:
             os.unlink(path)
         except OSError:

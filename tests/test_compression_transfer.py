@@ -112,7 +112,14 @@ class TestCompressedObjectStore:
         obj.put(key, _quantized(4 * MB))
         sink = []
         obj.fetch(key, DiskStore(str(tmp_path / "disk")), report_out=sink)
-        assert sink[0].overlap_s() > 0  # decode overlapped the transfer
+        report = sink[0]
+        wire, dec = report.stage("wire_read"), report.stage("decompress")
+        # decode began before the wire's last chunk was read. With queues
+        # of depth 2 the wire stage cannot start chunk n-1 until decompress
+        # has taken chunk n-4, so from 5 chunks on this holds on any host
+        # when, and only when, the stages run concurrently
+        assert report.n_chunks >= 5
+        assert dec.first_start_s < wire.last_end_s
 
     def test_pre_compression_manifest_compat(self, tmp_path):
         """Entries written before the codec era ({digest, nbytes} only, blob

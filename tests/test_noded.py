@@ -317,7 +317,6 @@ class TestDaemonLifecycle:
     def test_sigterm_clean_shutdown(self, tmp_path, register_daemon):
         key = ModelKey("jax", "m0", "1")
         make_model(str(tmp_path / "a"), key)
-        shm_before = set(glob.glob("/dev/shm/trims_*"))
         pa, ia = self._spawn(tmp_path, register_daemon, "a",
                              {"use_shm": True,
                               "directory": {"serve": True,
@@ -332,7 +331,12 @@ class TestDaemonLifecycle:
         r = tb.call({"op": "open", "key": list(key), "tier": "host",
                      "timeout": 60})
         assert r["timings"]["tier_hit"] == "peer"
-        assert set(glob.glob("/dev/shm/trims_*")) - shm_before
+
+        def owned(*procs):  # segments are named after the creating pid
+            return {path for p in procs
+                    for path in glob.glob(f"/dev/shm/trims_{p.pid}_*")}
+
+        assert owned(pb)
         holders = ta.call({"op": "dir.holders", "key": list(key)})["holders"]
         assert any(n == "b" for n, _ in holders)
 
@@ -344,7 +348,7 @@ class TestDaemonLifecycle:
         # ...and every shm segment b owned is unlinked
         pa.send_signal(signal.SIGTERM)
         assert pa.wait(timeout=15) == 0
-        leaked = set(glob.glob("/dev/shm/trims_*")) - shm_before
+        leaked = owned(pa, pb)
         assert not leaked, f"daemons leaked shm: {leaked}"
         ta.close(); tb.close()
 

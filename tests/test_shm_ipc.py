@@ -7,6 +7,7 @@ import json
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -15,6 +16,7 @@ from repro.core import DiskStore, MRM, ModelKey
 from repro.core.shm_ipc import MRMServer, RemoteTrimsClient
 
 MB = 1 << 20
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def _tensors(nbytes=2 * MB, n=4, seed=0):
@@ -75,10 +77,11 @@ CLIENT_SCRIPT = textwrap.dedent("""
 
 def test_cross_process_sharing(server, tmp_path):
     """Two OS processes open the same model: one load, shared bytes."""
-    script = CLIENT_SCRIPT.format(src="src", sock=server.sock_path)
+    script = CLIENT_SCRIPT.format(src=str(ROOT / "src"),
+                                  sock=server.sock_path)
     results = []
     for _ in range(2):
-        out = subprocess.run([sys.executable, "-c", script], cwd="/root/repo",
+        out = subprocess.run([sys.executable, "-c", script], cwd=ROOT,
                              capture_output=True, text=True, timeout=60)
         assert out.returncode == 0, out.stderr
         results.append(json.loads(out.stdout.strip().splitlines()[-1]))

@@ -54,8 +54,7 @@ def _write(tmp_path, tensors, name="m.trims"):
 
 def _mrm(disk, dev=64 * MB, host=256 * MB, **kw):
     return MRM(disk, device_capacity=dev, host_capacity=host,
-               hw=kw.pop("hw", HardwareModel()), pipelined_staging=False,
-               **kw)
+               hw=kw.pop("hw", HardwareModel()), **kw)
 
 
 # ------------------------------------------------------------- layer planner

@@ -127,7 +127,7 @@ class TestPipelinedStaging:
         h = mrm.open(key)
         assert h.timings.chunks > 1
         assert h.timings.stage_overlap_s >= 0.0
-        assert mrm.metrics["pipelined_loads"] == 1
+        assert mrm.metrics["disk_loads"] == 1
         for k in t:
             np.testing.assert_array_equal(np.asarray(h.weights[k]), t[k])
         mrm.close(h)
@@ -155,13 +155,27 @@ class TestPipelinedStaging:
             if e.payload is not None:
                 e.payload.release()
 
-    def test_serial_mode_still_works(self, disk):
-        key = ModelKey("jax", "serial")
+    def test_cold_device_open_is_host_load_then_stage(self, disk):
+        key = ModelKey("jax", "route")
         t = _tensors(seed=5)
         disk.put(key, t)
-        mrm = _mrm(disk, pipelined_staging=False)
+        mrm = _mrm(disk)
         h = mrm.open(key)
-        assert h.timings.chunks == 0
+        assert h.timings.tier_hit == "disk"
+        host = mrm.host.peek(key).payload.arrays
+        for k in t:
+            np.testing.assert_array_equal(host[k], t[k])
+            np.testing.assert_array_equal(np.asarray(h.weights[k]), t[k])
+        assert mrm.metrics["disk_loads"] == 1
+        assert mrm.metrics["h2d_stages"] == 1
+        mrm.close(h)
+
+        # the host copy the cold open left serves the next device open
+        mrm.device.remove(key)
+        h = mrm.open(key)
+        assert h.timings.tier_hit == "host"
+        assert mrm.metrics["disk_loads"] == 1
+        assert mrm.metrics["h2d_stages"] == 2
         for k in t:
             np.testing.assert_array_equal(np.asarray(h.weights[k]), t[k])
         mrm.close(h)
